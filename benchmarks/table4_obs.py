@@ -32,7 +32,7 @@ from repro import obs
 from repro.configs.base import ShapeCell
 from repro.launch.cells import build_cell
 from repro.launch.common import CellOptions
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.pipelines import TrainConfig, Trainer
 
 STEPS = 50
@@ -52,7 +52,7 @@ MICRO_NS_PREV = {
 
 def _steps_per_s(telemetry: bool, workdir: pathlib.Path) -> float:
     shape = ShapeCell("train_batch", "train", {"batch": 32})
-    cell = build_cell("wide-deep", "train_batch", make_test_mesh(),
+    cell = build_cell("wide-deep", "train_batch", make_mesh(),
                       CellOptions(remat=False, zero1=False),
                       smoke=True, shape_override=shape)
     cfg = TrainConfig(

@@ -25,9 +25,9 @@ OPTS = CellOptions(remat=False, zero1=False)
 
 
 def mesh1():
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_mesh
 
-    return make_test_mesh()
+    return make_mesh()
 
 
 def main():

@@ -82,15 +82,31 @@ def _owner_of(ids: jax.Array, n_devices: int) -> jax.Array:
     return jnp.where(ids == PAD, n_devices, own)
 
 
+def unique(ids: jax.Array, size: int) -> tuple[jax.Array, jax.Array]:
+    """Sorted unique ids padded with PAD to ``size``, and each id's index in
+    them — ``jnp.unique(ids, size=size, fill_value=PAD, return_inverse=True)``
+    with ONE sort. Uniques past ``size`` are dropped; their ids get index
+    ``size - 1``, which callers detect as ``uniq[inv] != ids``.
+
+    jnp.unique sorts twice (the values, then a stable argsort for the
+    inverse); each large 64-bit sort costs tens of seconds of TPU compile.
+    """
+    n = ids.shape[0]
+    srt, perm = jax.lax.sort((ids, jnp.arange(n, dtype=jnp.int32)), num_keys=1)
+    first = jnp.concatenate([jnp.ones((1,), bool), srt[1:] != srt[:-1]])
+    g = jnp.cumsum(first.astype(jnp.int32)) - 1          # unique index, sorted order
+    uniq = jnp.full((size,), PAD, ids.dtype).at[jnp.where(first, g, size)].set(
+        srt, mode="drop")
+    inv = jnp.zeros((n,), jnp.int32).at[perm].set(jnp.minimum(g, size - 1))
+    return uniq, inv
+
+
 def build_send(
     ids: jax.Array, spec: ExchangeSpec
 ) -> tuple[jax.Array, Plan, dict]:
     """Requester side: dedupe + bucket-by-owner. Returns (send_ids[D,C], plan⁰)."""
     D, U, C = spec.n_devices, spec.u_budget, spec.per_dest_cap
-    uniq, inv = jnp.unique(
-        ids, size=U, fill_value=PAD, return_inverse=True
-    )
-    inv = inv.reshape(ids.shape)
+    uniq, inv = unique(ids, U)
     # budget overflow: a value whose unique was truncated points at a wrong
     # slot — detect and mask (counted).
     ok_val = (uniq[inv] == ids) & (ids != PAD)
@@ -127,10 +143,7 @@ def build_send(
 def owner_merge(recv_ids: jax.Array, spec: ExchangeSpec) -> tuple[jax.Array, jax.Array, jax.Array, dict]:
     """Owner side: merge + unique the D*C received ids (paper's request merge)."""
     flat = recv_ids.reshape(-1)
-    uniq_r, inv_r = jnp.unique(
-        flat, size=spec.recv_budget, fill_value=PAD, return_inverse=True
-    )
-    inv_r = inv_r.reshape(flat.shape).astype(jnp.int32)
+    uniq_r, inv_r = unique(flat, spec.recv_budget)
     ok_r = (uniq_r[inv_r] == flat) & (flat != PAD)
     metrics = {"exch_recv_overflow": ((flat != PAD) & ~ok_r).sum(dtype=jnp.int32)}
     return uniq_r, inv_r, ok_r, metrics

@@ -32,9 +32,7 @@ from typing import Callable
 import jax
 import numpy as np
 
-# Per-chip peaks — TPU v5e (assignment constants).
-PEAK_HBM_BW = 819e9
-PEAK_FLOPS = 197e12
+from repro.roofline.analysis import chip_peaks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,7 +95,7 @@ class MBUResult:
     essential_bytes: int
     wall_s: float
     achieved_bw: float        # essential_bytes / wall_s
-    mbu: float                # achieved_bw / PEAK_HBM_BW (target hardware)
+    mbu: float                # achieved_bw / the target's peak HBM bytes/s
     moved_bytes: int | None = None
     bandwidth_intensity: float | None = None   # essential / moved
 
@@ -108,13 +106,13 @@ class MBUResult:
                 f"BI={bi} MBU={self.mbu*100:6.2f}%")
 
 
-def measure(traffic: OpTraffic, fn: Callable, *args, iters: int = 10,
-            warmup: int = 2, registry=None) -> MBUResult:
-    """Wall-time MBU of ``fn(*args)`` on the current backend.
+def measure(traffic: OpTraffic, fn: Callable, *args, target: str,
+            iters: int = 10, warmup: int = 2, registry=None) -> MBUResult:
+    """Wall-time MBU of ``fn(*args)`` on the current backend, against the
+    peak HBM bandwidth of ``target`` (a ``device_kind`` in ``CHIP_PEAKS``).
 
-    On this CPU container the absolute MBU is not meaningful against the
-    v5e peak; the harness reports *relative* numbers (fused vs naive on the
-    same backend), which is the paper's Table-1 comparison shape.
+    The MBU is a device metric only when the backend IS ``target``; a CPU
+    timing over a TPU peak is a relative number, never a device MBU.
 
     ``registry`` (an ``obs.MetricsRegistry``) folds the result into the
     unified ``mbu/`` namespace so kernel-quality and runtime metrics land
@@ -129,14 +127,14 @@ def measure(traffic: OpTraffic, fn: Callable, *args, iters: int = 10,
     dt = (time.perf_counter() - t0) / iters
     bw = traffic.essential_bytes / dt
     res = MBUResult(traffic.name, traffic.essential_bytes, dt, bw,
-                    bw / PEAK_HBM_BW)
+                    bw / chip_peaks(target).hbm_bw)
     if registry is not None:
         from repro.obs import record_mbu
         record_mbu(res, registry)
     return res
 
 
-def structural(traffic: OpTraffic, fn: Callable, *args,
+def structural(traffic: OpTraffic, fn: Callable, *args, target: str,
                registry=None) -> MBUResult:
     """Dry-run MBU: essential vs compiled `bytes accessed` (moved bytes).
 
@@ -147,11 +145,9 @@ def structural(traffic: OpTraffic, fn: Callable, *args,
     """
     lowered = jax.jit(fn).lower(*args)
     cost = lowered.compile().cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns one dict/device
-        cost = cost[0] if cost else {}
     moved = int(cost.get("bytes accessed", 0)) or None
     bi = traffic.essential_bytes / moved if moved else None
-    wall = (moved or traffic.essential_bytes) / PEAK_HBM_BW
+    wall = (moved or traffic.essential_bytes) / chip_peaks(target).hbm_bw
     res = MBUResult(
         traffic.name, traffic.essential_bytes, wall,
         traffic.essential_bytes / wall, bi or 0.0,

@@ -25,10 +25,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import ZERO
+
+NEG_INF = np.float32(-1e30)
 
 
 def _dot(a, b, ta=False, tb=False):
@@ -104,13 +107,13 @@ def flash_fwd(
                           causal=causal, nk=nk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, 0)),
+            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, ZERO)),
         ],
         out_specs=(
-            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, tq, 1), lambda b, qb, kb: (b, qb, 0)),
+            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tq, 1), lambda b, qb, kb: (b, qb, ZERO)),
         ),
         out_shape=out_shapes,
         scratch_shapes=[
@@ -226,16 +229,16 @@ def flash_bwd(
                           causal=causal, nq=nq),
         grid=(bh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, tq, hd), lambda b, kb, qb: (b, qb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, 0)),
-            pl.BlockSpec((1, tq, hd), lambda b, kb, qb: (b, qb, 0)),
-            pl.BlockSpec((1, tq, 1), lambda b, kb, qb: (b, qb, 0)),
-            pl.BlockSpec((1, tq, 1), lambda b, kb, qb: (b, qb, 0)),
+            pl.BlockSpec((1, tq, hd), lambda b, kb, qb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, ZERO)),
+            pl.BlockSpec((1, tq, hd), lambda b, kb, qb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tq, 1), lambda b, kb, qb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tq, 1), lambda b, kb, qb: (b, qb, ZERO)),
         ],
         out_specs=(
-            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, 0)),
+            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, kb, qb: (b, kb, ZERO)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
@@ -253,14 +256,14 @@ def flash_bwd(
                           causal=causal, nk=nk),
         grid=(bh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, 0)),
-            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, 0)),
-            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, tq, 1), lambda b, qb, kb: (b, qb, 0)),
-            pl.BlockSpec((1, tq, 1), lambda b, qb, kb: (b, qb, 0)),
+            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, ZERO)),
+            pl.BlockSpec((1, tk, hd), lambda b, qb, kb: (b, kb, ZERO)),
+            pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tq, 1), lambda b, qb, kb: (b, qb, ZERO)),
+            pl.BlockSpec((1, tq, 1), lambda b, qb, kb: (b, qb, ZERO)),
         ],
-        out_specs=pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, 0)),
+        out_specs=pl.BlockSpec((1, tq, hd), lambda b, qb, kb: (b, qb, ZERO)),
         out_shape=jax.ShapeDtypeStruct((bh, t, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((tq, hd), jnp.float32)],
         interpret=interpret,

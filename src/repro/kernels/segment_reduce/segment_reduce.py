@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import ZERO
+
 
 def _kernel(seg_ref, val_ref, out_ref, *, ts: int, tn: int):
     """One (s, n) grid step: accumulate ohᵀ @ values into out tile s."""
@@ -106,10 +108,10 @@ def segment_sum_padded(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((tn, 1), lambda s, n_, b: (n_, 0)),
-                pl.BlockSpec((tn, d), lambda s, n_, b: (n_, 0)),
+                pl.BlockSpec((tn, 1), lambda s, n_, b: (n_, ZERO)),
+                pl.BlockSpec((tn, d), lambda s, n_, b: (n_, ZERO)),
             ],
-            out_specs=pl.BlockSpec((ts, d), lambda s, n_, b: (s, 0)),
+            out_specs=pl.BlockSpec((ts, d), lambda s, n_, b: (s, ZERO)),
         )
         return pl.pallas_call(
             functools.partial(_kernel_skip, ts=ts, tn=tn),
@@ -122,10 +124,10 @@ def segment_sum_padded(
         functools.partial(_kernel, ts=ts, tn=tn),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tn, 1), lambda s, n_: (n_, 0)),
-            pl.BlockSpec((tn, d), lambda s, n_: (n_, 0)),
+            pl.BlockSpec((tn, 1), lambda s, n_: (n_, ZERO)),
+            pl.BlockSpec((tn, d), lambda s, n_: (n_, ZERO)),
         ],
-        out_specs=pl.BlockSpec((ts, d), lambda s, n_: (s, 0)),
+        out_specs=pl.BlockSpec((ts, d), lambda s, n_: (s, ZERO)),
         out_shape=jax.ShapeDtypeStruct((num_segments, d), values.dtype),
         interpret=interpret,
     )(seg2d, values)

@@ -7,6 +7,8 @@ lowers+compiles cells; ``train.py`` runs them with concrete data.
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 from typing import Any, Callable
 
 import jax
@@ -15,6 +17,25 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeCell
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other path is set here. Otherwise, on an accelerator, the cache is
+    ``.jax_cache/`` at the root of the checkout (a fixed path: the path is
+    part of the cache key); CPU compiles are not cached. Returns the
+    directory in use, or None.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.default_backend() == "cpu":
+        return None
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,6 +66,9 @@ class CellOptions:
     # device-tier rows per shard override when storage is on (the HBM cache
     # size); None keeps the arch-derived all-HBM sizing
     storage_device_rows: int | None = None
+    # rows of every embedding table one chip holds (the chip's share of a
+    # deployment); None keeps the arch's published table sizes
+    chip_table_rows: int | None = None
 
 
 @dataclasses.dataclass
@@ -65,6 +89,18 @@ class Cell:
         kwargs = {"donate_argnums": (0,)} if (self.donate_state and self.returns_state) else {}
         jitted = jax.jit(self.step_fn, **kwargs)
         return jitted.lower(self.abstract_state, self.batch_specs)
+
+    def shardings(self):
+        """(state, batch) pytrees of the NamedShardings the step runs under."""
+        def of(tree):
+            return jax.tree.map(lambda s: s.sharding, tree)
+
+        return of(self.abstract_state), of(self.batch_specs)
+
+    def init(self):
+        """Concrete state built on the devices straight into its shardings,
+        so a table is never materialised whole on one device first."""
+        return jax.jit(self.init_state, out_shardings=self.shardings()[0])()
 
 
 def named(mesh, spec: P) -> NamedSharding:

@@ -1,11 +1,13 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run (deliverable (e)).
 
 Lowers + compiles every (architecture × input shape) cell for the
 production meshes — 16×16 (single pod) and 2×16×16 (two pods) — and
 records memory_analysis / cost_analysis / collective schedule to JSON for
 EXPERIMENTS.md §Dry-run and the §Roofline tables.
+
+It runs on 512 forced host devices of the CPU backend and pins itself
+there, so it never claims an accelerator. Roofline terms divide those
+CPU-compiled counts by the peaks of ``TARGET``.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch dlrm-mlperf --shape train_batch
@@ -27,6 +29,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.roofline import analysis as ra
 
 REPORT_DIR = pathlib.Path(__file__).resolve().parents[3] / "reports" / "dryrun"
+TARGET = "TPU v5 lite"  # the device_kind whose peaks bound the roofline terms
 
 
 def _cost_of(compiled) -> dict:
@@ -40,8 +43,6 @@ def _cost_of(compiled) -> dict:
             if v is not None:
                 mem_d[k] = int(v)
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax < 0.5 returns one dict/device
-        cost = cost[0] if cost else {}
     cost_d = {k: float(v) for k, v in cost.items()
               if isinstance(v, (int, float)) and k in
               ("flops", "bytes accessed", "transcendentals", "utilization")}
@@ -117,6 +118,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
         hbm_bytes=hbm_bytes,
         coll_bytes=coll_bytes,
         chips=chips,
+        target=TARGET,
         model_flops=ra.model_flops(arch, shape),
     )
     rec = {
@@ -174,6 +176,9 @@ def main(argv=None):
     p.add_argument("--save-hlo", action="store_true",
                    help="save compiled HLO text (zstd) for offline re-accounting")
     args = p.parse_args(argv)
+    # before any backend starts: the CPU backend only, with 512 devices
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 512)
 
     jobs = []
     if args.all:

@@ -21,7 +21,7 @@ from repro.models import gnn
 from repro.models.gnn import GraphBatch
 from repro.models.layers import MIXED
 from repro.optim import adamw
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def _graph_specs(mesh, spec_map: dict) -> GraphBatch:

@@ -26,7 +26,7 @@ from repro.models.layers import MIXED
 from repro.models.transformer import MeshCtx
 from repro.optim import adamw
 from repro.optim.sparse_adam import SparseAdamConfig
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def _engine_for(cfg, mesh, L_local: int, opts: CellOptions) -> tuple[EmbeddingEngine, str]:

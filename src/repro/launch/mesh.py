@@ -1,54 +1,30 @@
-"""Production mesh definitions (multi-pod dry-run spec).
+"""``make_mesh`` makes every mesh: entry points, tests and the dry run.
+
+Axes are ``AxisType.Auto``: the cells mix GSPMD with ``jax.shard_map``
+islands, and under Explicit axes (``jax.make_mesh``'s default) the dense
+backward is refused with "Contracting dimensions are sharded".
 
 Functions, not module-level constants: importing this module never touches
-jax device state, so smoke tests keep their single real device.
+jax device state.
 """
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(shape=None, axes: tuple[str, ...] = ("data",), devices=None):
-    """Version-compat mesh builder for tests.
-
-    jax < 0.5 has no ``jax.sharding.AxisType`` and ``jax.make_mesh`` rejects
-    the ``axis_types`` kwarg; newer jax wants explicit Auto axes for the
-    shard_map/GSPMD mix the cells use. Pass ``axis_types`` only when the
-    running jax supports it so the same test code spans both.
-    """
-    import numpy as np
-
+def make_mesh(shape=None, axes: tuple[str, ...] = ("data",), devices=None):
+    """Mesh over ``devices`` (default: all of the default backend's)."""
     devs = np.array(jax.devices()) if devices is None else np.asarray(devices)
     if shape is None:
         shape = (devs.size,)
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devs, **kwargs)
+    return jax.make_mesh(shape, axes, devices=devs.reshape(-1),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_debug_mesh(n_devices: int | None = None):
-    """Small host mesh for multi-device tests (forced host devices)."""
-    n = n_devices or len(jax.devices())
-    assert n % 2 == 0, "debug mesh wants an even device count"
-    return jax.make_mesh((n // 2, 2), ("data", "model"))
+def make_production_mesh(*, multi_pod: bool = False, devices=None):
+    """16×16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
+    shape = (2, 16, 16) if multi_pod else (16, 16)
+    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
+    return make_mesh(shape, axes, devices)
 
-
-def mesh_axes(mesh) -> tuple[str, ...]:
-    return tuple(mesh.axis_names)
-
-
-def dp_axes(mesh) -> tuple[str, ...]:
-    """Batch axes = everything except the tensor/EP axis ("model")."""
-    return tuple(a for a in mesh.axis_names if a != "model")
-
-
-def n_devices(mesh) -> int:
-    return mesh.devices.size

@@ -26,7 +26,7 @@ from repro.launch.common import Cell, CellOptions, abstractify, mesh_info, round
 from repro.models.layers import MIXED
 from repro.optim import adamw
 from repro.optim.sparse_adam import SparseAdamConfig
-from repro.compat import shard_map
+from jax import shard_map
 
 _MODELS = {}
 
@@ -98,9 +98,11 @@ class _Plumbing:
         return P(None) if self.replicated else P(self.axes)
 
     def make_batch(self, seed: int, vocab: int = 1 << 30):
-        """Concrete synthetic batch (power-law ids) matching batch_struct."""
+        """Concrete synthetic batch (power-law ids) matching batch_struct,
+        placed on its shardings: each device receives only its slice."""
         r = np.random.default_rng(seed)
         rep = 1 if self.replicated else self.D
+        sh = jax.NamedSharding(self.mesh, self.in_spec())
         out = {}
         for s in self.specs:
             n = self.nnz_loc[s.name]
@@ -112,7 +114,8 @@ class _Plumbing:
             else:
                 vals = (r.zipf(1.2, size=(rep * n,)) % vocab).astype(np.int64)
             splits = np.tile(np.arange(self.b_loc + 1, dtype=np.int32) * k, rep)
-            out[s.name] = Ragged(jnp.asarray(vals), jnp.asarray(splits))
+            out[s.name] = Ragged(jax.device_put(vals, sh),
+                                 jax.device_put(splits, sh))
         return out
 
     def prepared(self, batch_local: Mapping[str, Ragged]):
@@ -120,22 +123,23 @@ class _Plumbing:
         return self.fengine.apply(batch_local)
 
 
-def _rows_per_dim(arch: ArchConfig) -> dict[int, int]:
-    """Global KV row capacity per dim-group (table sizes from the arch)."""
+def _rows_per_dim(arch: ArchConfig, table_rows: int | None = None) -> dict[int, int]:
+    """Global KV row capacity per dim-group: the arch's table sizes, or
+    ``table_rows`` rows for every table when given."""
     m = arch.model
-    if arch.arch_id == "dlrm-mlperf":
-        return {m.embed_dim: m.n_sparse * m.vocab_per_feature}
-    if arch.arch_id == "wide-deep":
-        return {m.embed_dim: m.n_sparse * m.vocab_per_feature,
-                m.wide_dim: m.n_sparse * m.vocab_per_feature}
-    return {m.embed_dim: m.vocab}  # sasrec / mind: one shared item table
+    if arch.arch_id in ("dlrm-mlperf", "wide-deep"):
+        rows = m.n_sparse * (table_rows or m.vocab_per_feature)
+        dims = (m.embed_dim, m.wide_dim) if arch.arch_id == "wide-deep" else (m.embed_dim,)
+        return {d: rows for d in dims}
+    return {m.embed_dim: table_rows or m.vocab}  # sasrec / mind: one shared item table
 
 
 def _plumbing(arch: ArchConfig, mesh, b_loc: int, specs: list[FeatureSpec],
               opts: CellOptions, replicated: bool = False) -> _Plumbing:
     mi = mesh_info(mesh)
     D = mi["D"]
-    rows_global = _rows_per_dim(arch)
+    rows_global = _rows_per_dim(
+        arch, opts.chip_table_rows and opts.chip_table_rows * D)
     by_dim: dict[int, int] = {}
     for s in specs:
         if s.emb_dim is not None:
@@ -143,7 +147,8 @@ def _plumbing(arch: ArchConfig, mesh, b_loc: int, specs: list[FeatureSpec],
     overrides = {}
     for dim, L in by_dim.items():
         u = max(round_up(L, 8), 16)
-        c = max(8, round_up(int(np.ceil(u / D * opts.capacity_slack)), 8))
+        # no destination can receive more than the U unique ids
+        c = min(u, max(8, round_up(int(np.ceil(u / D * opts.capacity_slack)), 8)))
         r = min(D * c, max(round_up(int(opts.recv_slack * u), 8), 64))
         rows = max(round_up(int(rows_global.get(dim, 1 << 20) * 1.5 / D), 128), 1024)
         if opts.storage is not None and opts.storage_device_rows is not None:

@@ -1,12 +1,16 @@
 """End-to-end train driver: --arch/--shape → cell → Trainer loop.
 
-On real TPU pods this runs under the production mesh; on this CPU container
-it runs the reduced (smoke) config of the same arch on the available
-devices — the full configs are exercised via ``dryrun.py``.
+It runs on a one-axis ("data",) mesh over every device of the default
+backend. ``--config smoke`` (the default) is the reduced same-family
+config the CPU tests use; ``--config published`` is the arch at its
+published widths, with ``--table-rows`` rows of every embedding table on
+each chip (the chip's share of the deployment's tables).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --arch dlrm-mlperf \
       --steps 100 --batch 256 --ckpt-dir /tmp/ckpt [--resume]
+  python -m repro.launch.train --arch dlrm-mlperf --config published \
+      --table-rows 131072 --batch 65536 --steps 3     # on a TPU chip
 
 With ``--data-dir`` (recsys archs, single-device smoke mesh) batches
 stream from a ColumnIO table through an AsyncLoader instead of the
@@ -22,25 +26,20 @@ import pathlib
 import sys
 
 import jax
-import numpy as np
 
 from repro import obs
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ShapeCell
 from repro.ft.chaos import InjectedCrash
 from repro.launch.cells import build_cell
-from repro.launch.common import CellOptions
+from repro.launch.common import CellOptions, enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.pipelines import TrainConfig, Trainer
 
 CHAOS_EXIT = 42  # an injected crash is "the process died here" — not an error
 
 
-def small_mesh():
-    devs = np.array(jax.devices())
-    return jax.make_mesh((devs.size,), ("data",), devices=devs)
-
-
-def smoke_shape(arch, shape_name: str | None, batch: int, seq_len: int) -> ShapeCell:
+def run_shape(arch, shape_name: str | None, batch: int, seq_len: int) -> ShapeCell:
     fam = arch.family
     if fam == "lm":
         return ShapeCell(shape_name or "train_4k", "train",
@@ -50,11 +49,6 @@ def smoke_shape(arch, shape_name: str | None, batch: int, seq_len: int) -> Shape
     return ShapeCell(shape_name or "molecule", "graph_batch",
                      {"n_nodes": 12, "n_edges": 24, "batch": batch,
                       "d_feat": 16, "n_classes": 2})
-
-
-def make_evict_fn(cell):
-    """Between-window stale-row eviction on the cell's sparse state (if any)."""
-    return None  # cells fold eviction into the engine; exposed via examples
 
 
 def _with_step_chaos(stream, chaos, start: int):
@@ -67,9 +61,15 @@ def _with_step_chaos(stream, chaos, start: int):
         yield batch
 
 
-def main(argv=None) -> int:
+def parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True, choices=ARCH_IDS)
+    p.add_argument("--config", choices=("smoke", "published"), default="smoke",
+                   help="smoke = reduced same-family config; published = "
+                        "the arch's published widths")
+    p.add_argument("--table-rows", type=int, default=None, metavar="N",
+                   help="rows of every embedding table each chip holds "
+                        "(default: the config's table sizes)")
     p.add_argument("--shape", default=None)
     p.add_argument("--steps", type=int, default=100)
     p.add_argument("--batch", type=int, default=64)
@@ -124,29 +124,39 @@ def main(argv=None) -> int:
     p.add_argument("--aggregate", nargs="*", default=None, metavar="GLOB",
                    help="tail peer telemetry files; publishes agg/* and "
                         "gates the autoscaler on the fleet queue")
-    args = p.parse_args(argv)
+    return p
+
+
+def run(args: argparse.Namespace, devices=None):
+    """Build the cell and train ``args.steps`` steps on a ("data",) mesh over
+    ``devices`` (default: all of the default backend's). Returns
+    (TrainResult, Trainer, Cell). Argument errors raise SystemExit(2)."""
+    def error(msg):
+        print(f"train: error: {msg}", file=sys.stderr)
+        raise SystemExit(2)
 
     if args.snapshot_every and not args.telemetry:
-        p.error("--snapshot-every requires --telemetry (snapshots ride the "
-                "JSONL trace)")
-
+        error("--snapshot-every requires --telemetry (snapshots ride the "
+              "JSONL trace)")
     if args.autoscale and not args.data_dir:
-        p.error("--autoscale requires --data-dir (nothing to scale without "
-                "an AsyncLoader)")
+        error("--autoscale requires --data-dir (nothing to scale without "
+              "an AsyncLoader)")
 
-    mesh = small_mesh()
-    arch = get_config(args.arch, smoke=True)
-    shape = smoke_shape(arch, args.shape, args.batch, args.seq_len)
-    opts = CellOptions(use_pallas=args.use_pallas, remat=False, zero1=False)
-    cell = build_cell(args.arch, shape.name, mesh, opts, smoke=True,
+    mesh = make_mesh(devices=devices)
+    smoke = args.config == "smoke"
+    arch = get_config(args.arch, smoke=smoke)
+    shape = run_shape(arch, args.shape, args.batch, args.seq_len)
+    opts = CellOptions(use_pallas=args.use_pallas, remat=False, zero1=False,
+                       chip_table_rows=args.table_rows)
+    cell = build_cell(args.arch, shape.name, mesh, opts, smoke=smoke,
                       shape_override=shape)
 
     loader = controller = None
     if args.data_dir:
         if arch.family != "recsys":
-            p.error("--data-dir is a recsys-family data path")
-        if np.array(jax.devices()).size != 1:
-            p.error("--data-dir streaming needs a single-device smoke mesh")
+            error("--data-dir is a recsys-family data path")
+        if mesh.devices.size != 1:
+            error("--data-dir streaming needs a single-device mesh")
         from repro.io import datagen
         from repro.io.columnio import AsyncLoader, BatchSpec
         from repro.launch.recsys_cell import _ids_per_row, _model_mod
@@ -188,14 +198,14 @@ def main(argv=None) -> int:
         print(f"chaos schedule: {sched}")
     if args.ckpt_mode == "delta":
         if not args.ckpt_dir:
-            p.error("--ckpt-mode delta requires --ckpt-dir")
+            error("--ckpt-mode delta requires --ckpt-dir")
         hooks = getattr(cell, "storage_hooks", None)
         if hooks is None:
             engine = getattr(cell, "engine", None)
             ids_fn = getattr(cell, "ids_fn", None)
             if engine is None or ids_fn is None:
-                p.error("--ckpt-mode delta needs a sparse-engine arch "
-                        "(recsys family)")
+                error("--ckpt-mode delta needs a sparse-engine arch "
+                      "(recsys family)")
             from repro.ft import FTTrainerHooks
             hooks = FTTrainerHooks(engine, ids_fn, state_key="sparse")
 
@@ -216,7 +226,7 @@ def main(argv=None) -> int:
         print(f"prometheus: serving /metrics on port {exporter.start()}")
 
     with mesh:
-        state = cell.init_state()
+        state = cell.init()
         state, start, cursor = trainer.try_resume(state)
         if start:
             print(f"resumed from step {start} (cursor={cursor})")
@@ -244,6 +254,18 @@ def main(argv=None) -> int:
         loader.stop()
     if exporter is not None:
         exporter.stop()
+    if controller is not None:
+        print(f"autoscale: {len(controller.actions_log)} actions, "
+              f"final readers={loader.n_readers}")
+        for s, act in controller.actions_log:
+            print(f"  step {s}: {act}")
+    return res, trainer, cell
+
+
+def main(argv=None) -> int:
+    args = parser().parse_args(argv)
+    enable_compile_cache()
+    res, _, _ = run(args)
     for m in res.metrics_history[-5:]:
         print({k: round(v, 5) if isinstance(v, float) else v for k, v in m.items()})
     print(f"ran {res.steps_run} steps"
@@ -262,11 +284,6 @@ def main(argv=None) -> int:
             s = snap[name]
             print(f"{name:28s} p50={s['p50']*1e3:8.3f}ms "
                   f"p99={s['p99']*1e3:8.3f}ms total={s['sum']:.3f}s")
-    if controller is not None:
-        print(f"autoscale: {len(controller.actions_log)} actions, "
-              f"final readers={loader.n_readers}")
-        for s, act in controller.actions_log:
-            print(f"  step {s}: {act}")
     if args.telemetry:
         print(f"telemetry trace: {args.telemetry}")
     return 0

@@ -111,7 +111,9 @@ def causal_attention(
     if impl == "skip":
         return q.reshape(b, t, h * hd)
     g = h // k.shape[2]
-    if impl == "pallas" and not (t % 128):
+    if impl == "pallas":
+        if t % 128:
+            raise ValueError(f"impl='pallas' needs seq_len % 128 == 0, got {t}")
         from repro.kernels.flash_attention import ops as fa_ops
 
         return fa_ops.flash_attention(

@@ -27,7 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
-from repro.compat import shard_map
+from jax import shard_map
 from repro.models.layers import (
     MIXED, Precision, dense_apply, dense_pspec, make_dense, make_rmsnorm,
     make_swiglu, rmsnorm_apply, swiglu_apply, swiglu_pspec,

@@ -31,6 +31,22 @@ from repro import obs
 from repro.checkpoint import saver as saver_lib
 
 
+class _CompileCounter:
+    """Backend compiles while registered as a JAX monitoring listener: a step
+    that compiles after the first is a retrace, and its count and seconds
+    are logged."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n, self.s = 0, 0.0
+
+    def __call__(self, event: str, secs: float, **_) -> None:
+        if event == self.EVENT:
+            self.n += 1
+            self.s += secs
+
+
 @dataclasses.dataclass
 class TrainConfig:
     total_steps: int = 100
@@ -229,7 +245,15 @@ class Trainer:
         # this step's data_wait, not a lagging aggregate (DESIGN.md §10).
         self.controller = controller
         donate = (0,) if (cell.donate_state and cell.returns_state) else ()
-        self._jit_step = jax.jit(cell.step_fn, donate_argnums=donate)
+        shard = {}
+        if getattr(cell, "abstract_state", None) is not None:
+            # pinned in and out, so step N's state is step N+1's input as is
+            # and no step after the first compiles again
+            state_sh, batch_sh = cell.shardings()
+            shard["in_shardings"] = (state_sh, batch_sh)
+            if cell.returns_state:
+                shard["out_shardings"] = (state_sh, None)
+        self._jit_step = jax.jit(cell.step_fn, donate_argnums=donate, **shard)
         self.registry = registry if registry is not None else obs.get_registry()
         self.writer = (obs.TelemetryWriter(cfg.telemetry_path)
                        if cfg.telemetry_path else None)
@@ -256,6 +280,11 @@ class Trainer:
         # snapshot epoch: bumped to the resume step by run() so counters
         # from different process incarnations merge additively (§12/§13)
         self._epoch = 0
+
+    def compiled(self):
+        """The step's compiled executable, from the jit cache once a step ran."""
+        return self._jit_step.lower(self.cell.abstract_state,
+                                    self.cell.batch_specs).compile()
 
     def _init_delta_ckpt(self):
         """ft_mode="delta": dirty-row tracking + incremental frames on a
@@ -375,89 +404,99 @@ class Trainer:
         it = iter(batches)
         c_steps = reg.counter("trainer/steps")
         c_straggler = reg.counter("trainer/straggler_events")
+        c_compiles = reg.counter("trainer/compiles")
         h_wall = reg.histogram("trainer/step_wall_s")
         g_step = reg.gauge("trainer/last_step")
 
-        while step < cfg.total_steps:
-            with self.tracer.step(step + 1) as st:
-                with self.tracer.span("data_wait"):
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        st.cancel()
-                        break
-                t0 = time.perf_counter()
-                hook_metrics: dict = {}
-                if self.hooks is not None:
-                    with self.tracer.span("pre_step"):
-                        state, hook_metrics = self.hooks.pre_step(
-                            state, batch, step + 1)
-                with self.tracer.span("device_step"):
-                    if self.cell.returns_state:
-                        state, metrics = self._jit_step(state, batch)
-                    else:
-                        metrics = self._jit_step(state, batch)
-                    jax.block_until_ready(metrics)
-                if self.hooks is not None:
-                    with self.tracer.span("post_step"):
-                        state, post_m = self.hooks.post_step(state, step + 1)
-                    hook_metrics.update(post_m)
-                dt = time.perf_counter() - t0
-                step += 1
+        compiles = _CompileCounter()
+        jax.monitoring.register_event_duration_secs_listener(compiles)
+        try:
+            while step < cfg.total_steps:
+                with self.tracer.step(step + 1) as st:
+                    with self.tracer.span("data_wait"):
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            st.cancel()
+                            break
+                    t0 = time.perf_counter()
+                    n0, s0 = compiles.n, compiles.s
+                    hook_metrics: dict = {}
+                    if self.hooks is not None:
+                        with self.tracer.span("pre_step"):
+                            state, hook_metrics = self.hooks.pre_step(
+                                state, batch, step + 1)
+                    with self.tracer.span("device_step"):
+                        if self.cell.returns_state:
+                            state, metrics = self._jit_step(state, batch)
+                        else:
+                            metrics = self._jit_step(state, batch)
+                        jax.block_until_ready(metrics)
+                    if self.hooks is not None:
+                        with self.tracer.span("post_step"):
+                            state, post_m = self.hooks.post_step(state, step + 1)
+                        hook_metrics.update(post_m)
+                    dt = time.perf_counter() - t0
+                    step += 1
+                    hook_metrics.update(compiles=compiles.n - n0,
+                                        compile_s=compiles.s - s0)
 
-                c_steps.inc()
-                h_wall.observe(dt)
-                g_step.set(step)
-                self._accumulate(interval, hook_metrics)
+                    c_steps.inc()
+                    c_compiles.inc(hook_metrics["compiles"])
+                    h_wall.observe(dt)
+                    g_step.set(step)
+                    self._accumulate(interval, hook_metrics)
 
-                slow = cfg.watchdog and self.watchdog.observe(
-                    step, dt, st.spans)
-                if slow:
-                    c_straggler.inc()
-                if self.anomaly is not None:
-                    self.anomaly.observe_step(step, st.spans)
-                m_scalar = {k: float(np.asarray(v)) for k, v in metrics.items()
-                            if np.ndim(v) == 0}
-                st.annotate(wall_s=dt, straggler=bool(slow), metrics=m_scalar)
-                if slow and self.watchdog.events:
-                    st.annotate(straggler_phase=self.watchdog.events[-1].phase)
+                    slow = cfg.watchdog and self.watchdog.observe(
+                        step, dt, st.spans)
+                    if slow:
+                        c_straggler.inc()
+                    if self.anomaly is not None:
+                        self.anomaly.observe_step(step, st.spans)
+                    m_scalar = {k: float(np.asarray(v)) for k, v in metrics.items()
+                                if np.ndim(v) == 0}
+                    st.annotate(wall_s=dt, straggler=bool(slow), metrics=m_scalar)
+                    if slow and self.watchdog.events:
+                        st.annotate(straggler_phase=self.watchdog.events[-1].phase)
 
-                if step % cfg.log_every == 0 or slow:
-                    m = dict(m_scalar)
-                    m.update(self._finalize_interval(interval))
-                    interval = {}
-                    m.update(step=step, wall_s=dt, straggler=bool(slow))
-                    history.append(m)
+                    if step % cfg.log_every == 0 or slow:
+                        m = dict(m_scalar)
+                        m.update(self._finalize_interval(interval))
+                        interval = {}
+                        m.update(step=step, wall_s=dt, straggler=bool(slow))
+                        history.append(m)
 
-                if self.controller is not None:
-                    with self.tracer.span("autoscale"):
-                        self.controller.on_step(step, st.spans)
+                    if self.controller is not None:
+                        with self.tracer.span("autoscale"):
+                            self.controller.on_step(step, st.spans)
 
-                if (cfg.evict_every and self.evict_fn
-                        and step % cfg.evict_every == 0):
-                    with self.tracer.span("evict"):
-                        state = self.evict_fn(
-                            state, max(step - cfg.evict_age_steps, 0))
+                    if (cfg.evict_every and self.evict_fn
+                            and step % cfg.evict_every == 0):
+                        with self.tracer.span("evict"):
+                            state = self.evict_fn(
+                                state, max(step - cfg.evict_age_steps, 0))
 
-                if eval_fn and cfg.eval_every and step % cfg.eval_every == 0:
-                    with self.tracer.span("eval"):
-                        history.append(
-                            {"step": step,
-                             **{f"eval_{k}": v for k, v in
-                                eval_fn(state, step).items()}})
+                    if eval_fn and cfg.eval_every and step % cfg.eval_every == 0:
+                        with self.tracer.span("eval"):
+                            history.append(
+                                {"step": step,
+                                 **{f"eval_{k}": v for k, v in
+                                    eval_fn(state, step).items()}})
 
-                if cfg.ckpt_every and step % cfg.ckpt_every == 0:
-                    self._save(state, step,
-                               cursor_fn() if cursor_fn else None)
+                    if cfg.ckpt_every and step % cfg.ckpt_every == 0:
+                        self._save(state, step,
+                                   cursor_fn() if cursor_fn else None)
 
-                if cfg.snapshot_every and step % cfg.snapshot_every == 0:
-                    self._emit_snapshot(step)
+                    if cfg.snapshot_every and step % cfg.snapshot_every == 0:
+                        self._emit_snapshot(step)
 
-            if self.reporter is not None:
-                self.reporter.maybe_report(step)
-            if guard.requested:
-                preempted = True
-                break
+                if self.reporter is not None:
+                    self.reporter.maybe_report(step)
+                if guard.requested:
+                    preempted = True
+                    break
+        finally:
+            jax.monitoring.unregister_event_duration_listener(compiles)
 
         # final (or preemption) checkpoint — blocking, then restore handlers
         self._save(state, step, cursor_fn() if cursor_fn else None, blocking=True)

@@ -6,9 +6,11 @@ Implements BOTH performance models:
     for the sparse path (§1.4.2 Performance Modeling).
 
 Terms (per (arch × shape × mesh), single-pod):
-  compute_s    = HLO_FLOPs / (chips × PEAK_FLOPS)
-  memory_s     = HLO_bytes / (chips × HBM_BW)
-  collective_s = Σ collective operand bytes / (chips × ICI_BW)
+  compute_s    = HLO_FLOPs / (chips × peak FLOP/s)
+  memory_s     = HLO_bytes / (chips × peak HBM bytes/s)
+  collective_s = Σ collective operand bytes / (chips × peak ICI bytes/s)
+
+with the peaks of a named target device (``CHIP_PEAKS``).
 
 IMPORTANT accounting note (verified empirically): ``compiled.cost_analysis``
 and the parsed HLO of an SPMD executable are **per device** — one chip's
@@ -22,10 +24,34 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e hardware constants (per chip) — from the assignment.
-PEAK_FLOPS = 197e12      # bf16
-HBM_BW = 819e9           # bytes/s
-ICI_BW = 50e9            # bytes/s/link (we use 1 link-equivalent per chip)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peak rates of one accelerator kind."""
+
+    flops: float       # bf16 FLOP/s
+    hbm_bw: float      # HBM bytes/s
+    hbm_bytes: int     # HBM capacity
+    ici_bw: float      # chip-to-chip interconnect bytes/s, all links
+
+
+# The one table of chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GiB
+# HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9,
+                             hbm_bytes=16 * 2**30, ici_bw=1600e9 / 8),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; an unknown device is an error, not a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(CHIP_PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -178,19 +204,24 @@ class Roofline:
     hbm_bytes: float              # PER-CHIP bytes accessed
     coll_bytes: float             # PER-CHIP collective operand bytes
     chips: int
+    target: str                   # device_kind whose peaks bound the terms
     model_flops: float = 0.0      # GLOBAL 6·N·D style useful-work estimate
 
     @property
+    def peaks(self) -> ChipPeaks:
+        return chip_peaks(self.target)
+
+    @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / self.peaks.ici_bw
 
     @property
     def bound(self) -> str:
@@ -216,7 +247,7 @@ class Roofline:
         bound of its dominant term. 1.0 = the useful work IS the bound."""
         if self.step_s == 0:
             return 0.0
-        return (self.model_flops / self.chips / PEAK_FLOPS) / self.step_s
+        return (self.model_flops / self.chips / self.peaks.flops) / self.step_s
 
     @property
     def mbu_bound(self) -> float:
@@ -228,6 +259,7 @@ class Roofline:
         return {
             "flops": self.flops, "hbm_bytes": self.hbm_bytes,
             "coll_bytes": self.coll_bytes, "chips": self.chips,
+            "target": self.target,
             "model_flops": self.model_flops,
             "compute_s": self.compute_s, "memory_s": self.memory_s,
             "collective_s": self.collective_s, "bound": self.bound,

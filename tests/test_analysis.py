@@ -90,7 +90,7 @@ class TestPurity:
         res = lint_snippet(tmp_path, """
             import functools
             from jax.experimental import pallas as pl
-            from repro.compat import shard_map
+            from jax import shard_map
 
             def outer(mesh, x):
                 def body(x_loc):

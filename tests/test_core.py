@@ -206,6 +206,23 @@ def _spec(u=32, c=64, r=64):
 
 
 class TestExchange:
+    @pytest.mark.parametrize("n,size,hi", [(20, 32, 50), (64, 16, 1000),
+                                           (40, 40, 5)])
+    def test_unique_matches_jnp_unique(self, rng, n, size, hi):
+        """One-sort unique == jnp.unique(size, fill PAD, return_inverse),
+        including PAD ids and more uniques than ``size``."""
+        ids = rng.integers(-1, hi, n).astype(np.int64)
+        uniq, inv = exchange.unique(jnp.asarray(ids), size)
+        want, want_inv = jnp.unique(jnp.asarray(ids), size=size,
+                                    fill_value=exchange.PAD,
+                                    return_inverse=True)
+        np.testing.assert_array_equal(np.asarray(uniq), np.asarray(want))
+        kept = np.isin(ids, np.asarray(want)[:len(np.unique(ids))])
+        np.testing.assert_array_equal(np.asarray(inv)[kept],
+                                      np.asarray(want_inv).reshape(-1)[kept])
+        # an id whose unique was dropped is detectable as uniq[inv] != id
+        assert (np.asarray(uniq)[np.asarray(inv)] == ids).tolist() == kept.tolist()
+
     def test_fetch_route_roundtrip(self, rng):
         spec = _spec()
         m = idmap_lib.create(256, 128)

@@ -23,7 +23,7 @@ def run_sub(body: str, n_dev: int = 8, timeout: int = 480) -> str:
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         assert jax.device_count() == {n_dev}
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
@@ -41,8 +41,8 @@ class TestExchangeMultiDevice:
             from repro.core.feature_engine import FeatureSpec
             from repro.io.ragged import Ragged
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((8,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("data",))
             specs = [FeatureSpec("f", transform="hash", emb_dim=8, pooling="sum")]
 
             def build(axes, n_dev):
@@ -93,8 +93,8 @@ class TestExchangeMultiDevice:
             from repro.io.ragged import Ragged
             from repro.optim.sparse_adam import SparseAdamConfig
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((8,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("data",))
             specs = [FeatureSpec("f", transform="hash", emb_dim=4, pooling="sum")]
             eng = EmbeddingEngine(specs, EngineConfig(
                 mesh_axes=("data",), n_devices=8, rows_per_shard=256,
@@ -134,8 +134,8 @@ class TestCellsMultiDevice:
             from repro.launch.cells import build_cell
             from repro.launch.common import CellOptions
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((4, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((4, 2), ("data", "model"))
             shape = ShapeCell("train_batch", "train", {"batch": 32})
             cell = build_cell("dlrm-mlperf", "train_batch", mesh,
                               CellOptions(remat=False, zero1=False),
@@ -156,8 +156,8 @@ class TestCellsMultiDevice:
             from repro.launch.cells import build_cell
             from repro.launch.common import CellOptions
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             shape = ShapeCell("train_4k", "train", {"seq_len": 32, "global_batch": 4})
             cell = build_cell("qwen2.5-3b", "train_4k", mesh,
                               CellOptions(remat=False, zero1=True),
@@ -179,8 +179,8 @@ class TestCellsMultiDevice:
             from repro.launch.cells import build_cell
             from repro.launch.common import CellOptions
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             shape = ShapeCell("train_4k", "train", {"seq_len": 32, "global_batch": 4})
             cell = build_cell("qwen2-moe-a2.7b", "train_4k", mesh,
                               CellOptions(remat=False, zero1=False),
@@ -199,8 +199,8 @@ class TestCellsMultiDevice:
             from repro.models import transformer as tfm
             from repro.models.layers import FP32
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((2, 4), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2, 4), ("data", "model"))
             for n_kv in (4, 2):   # 4 = kv==tp path; 2 = GQA kv∤tp select path
                 cfg = tfm.TransformerConfig(name="t", n_layers=2, d_model=32,
                                             n_heads=8, n_kv_heads=n_kv,
@@ -232,8 +232,8 @@ class TestCellsMultiDevice:
             from repro.launch.cells import build_cell
             from repro.launch.common import CellOptions
 
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((8,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((8,), ("data",))
             shape = ShapeCell("molecule", "graph_batch",
                               {"n_nodes": 10, "n_edges": 20, "batch": 16,
                                "d_feat": 8, "n_classes": 2})
@@ -265,8 +265,8 @@ class TestCellsMultiDevice:
             from jax.sharding import PartitionSpec as P
 
             n_dev = jax.device_count()
-            from repro.launch.mesh import make_test_mesh
-            mesh = make_test_mesh((n_dev,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((n_dev,), ("data",))
             specs = [FeatureSpec("f", transform="hash", emb_dim=4, pooling="sum")]
             eng = EmbeddingEngine(specs, EngineConfig(
                 mesh_axes=("data",), n_devices=n_dev, rows_per_shard=128,

@@ -333,7 +333,7 @@ def telemetry_run(tmp_path_factory):
     from repro.configs.base import ShapeCell
     from repro.launch.cells import build_cell
     from repro.launch.common import CellOptions
-    from repro.launch.mesh import make_test_mesh
+    from repro.launch.mesh import make_mesh
     from repro.storage import StorageConfig
 
     tmp = tmp_path_factory.mktemp("obs")
@@ -344,7 +344,7 @@ def telemetry_run(tmp_path_factory):
     try:
         shape = ShapeCell("train_batch", "train", {"batch": 32})
         cell = build_cell(
-            "wide-deep", "train_batch", make_test_mesh(),
+            "wide-deep", "train_batch", make_mesh(),
             CellOptions(remat=False, zero1=False,
                         storage=StorageConfig(policy="lru"),
                         storage_device_rows=512),
@@ -441,7 +441,8 @@ class TestUnifiedNamespace:
         from repro.core import mbu
         reg = obs.MetricsRegistry()
         res = mbu.measure(mbu.t_mod(1024), lambda x: x % 97,
-                          jnp.arange(1024), iters=2, warmup=1, registry=reg)
+                          jnp.arange(1024), target="TPU v5 lite", iters=2,
+                          warmup=1, registry=reg)
         flat = reg.flat()
         assert flat["mbu/mod/mbu"] == pytest.approx(res.mbu)
         assert flat["mbu/mod/achieved_gbps"] > 0

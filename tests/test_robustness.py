@@ -188,7 +188,7 @@ class TestMBUModel:
         n = 1 << 16
         t = mbu.OpTraffic("copy", essential_bytes=8 * n)
         x = jnp.arange(n, dtype=jnp.float32)
-        res = mbu.structural(t, lambda v: v * 2.0, x)
+        res = mbu.structural(t, lambda v: v * 2.0, x, target="TPU v5 lite")
         assert res.moved_bytes is not None
         assert res.bandwidth_intensity is not None
         assert res.bandwidth_intensity > 0.5  # elementwise ≈ roofline

@@ -10,13 +10,13 @@ from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ShapeCell
 from repro.launch.cells import build_cell
 from repro.launch.common import CellOptions
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 
 OPTS = CellOptions(remat=False, zero1=False)
 
 
 def _mesh():
-    return make_test_mesh()
+    return make_mesh()
 
 
 def _smoke_shape(arch_id: str, kind: str) -> ShapeCell:

@@ -337,14 +337,14 @@ class TestTrainerAcceptance:
         from repro.configs.base import ShapeCell
         from repro.launch.cells import build_cell
         from repro.launch.common import CellOptions
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.pipelines import TrainConfig, Trainer
 
         steps = 15
         shape = ShapeCell("train_batch", "train", {"batch": 32})
 
         def run(opts, hooks):
-            cell = build_cell("wide-deep", "train_batch", make_test_mesh(),
+            cell = build_cell("wide-deep", "train_batch", make_mesh(),
                               opts, smoke=True, shape_override=shape)
             tr = Trainer(cell, TrainConfig(total_steps=steps, log_every=1,
                                            watchdog=False),
@@ -384,7 +384,7 @@ class TestTrainerAcceptance:
         from repro.configs.base import ShapeCell
         from repro.launch.cells import build_cell
         from repro.launch.common import CellOptions
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.pipelines import TrainConfig, Trainer
 
         shape = ShapeCell("train_batch", "train", {"batch": 32})
@@ -393,7 +393,7 @@ class TestTrainerAcceptance:
                            storage_device_rows=512)
 
         def run(ckpt, steps, resume):
-            cell = build_cell("wide-deep", "train_batch", make_test_mesh(),
+            cell = build_cell("wide-deep", "train_batch", make_mesh(),
                               opts, smoke=True, shape_override=shape)
             tr = Trainer(cell, TrainConfig(total_steps=steps,
                                            ckpt_dir=str(ckpt), ckpt_every=3,

@@ -10,14 +10,14 @@ import pytest
 from repro.configs.base import ShapeCell
 from repro.launch.cells import build_cell
 from repro.launch.common import CellOptions
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.pipelines import (
     OnlineWindowPipeline, StragglerWatchdog, TrainConfig, Trainer, multitask_loss,
 )
 
 
 def _mesh():
-    return make_test_mesh()
+    return make_mesh()
 
 
 def _cell(batch=32):
