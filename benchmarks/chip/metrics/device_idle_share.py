@@ -1,0 +1,9 @@
+"""1 - the union of device op intervals over the traced window, in %,
+averaged over the cell's chips."""
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
