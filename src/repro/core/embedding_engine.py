@@ -35,10 +35,23 @@ from repro.core import idmap as idmap_lib
 from repro.core import write_log
 from repro.core.feature_engine import FeatureSpec, hash_combine, splitmix64
 from repro.io.ragged import Ragged
+from repro.obs.stages import stage
 from repro.optim.sparse_adam import SparseAdamConfig, apply_row_updates
 from repro.storage.tiered import StorageConfig, TieredEmbeddingStore
 
 PAD = jnp.int64(-1)
+
+# per-chip metrics that are maxima, not counts: a probe pass runs
+# ``idmap_rounds`` on every chip, and the depths are the deepest round used
+MAX_METRICS = ("idmap_rounds", "idmap_probe_depth", "idmap_claim_depth")
+
+
+def reduce_metrics(metrics: Mapping[str, jax.Array], axes) -> dict:
+    """The engine's metrics across chips, inside shard_map: ``MAX_METRICS``
+    (under any ``<group>/`` prefix) by ``pmax``, every count by ``psum``."""
+    maxed = {k: v for k, v in metrics.items() if k.rsplit("/", 1)[-1] in MAX_METRICS}
+    summed = {k: v for k, v in metrics.items() if k not in maxed}
+    return {**jax.lax.psum(summed, axes), **jax.lax.pmax(maxed, axes)}
 
 
 def _stable_salt(name: str) -> int:
@@ -165,7 +178,8 @@ class EmbeddingEngine:
         """Runs INSIDE shard_map (local views, leading axis squeezed).
 
         Returns (state', rows_r {group: [R, dim]}, plans, metrics)."""
-        eng_ids = self.engine_ids(ids_by_feature)
+        with stage("recis.ids.hash"):
+            eng_ids = self.engine_ids(ids_by_feature)
         new_state, rows_r, plans, metrics = {}, {}, {}, {}
         for key, g in self.groups.items():
             m = state_local[key]["idmap"]
@@ -198,9 +212,10 @@ class EmbeddingEngine:
             ofs = 0
             for s in g.features:
                 r = ids_by_feature[s.name]
-                rows = vals[ofs: ofs + r.nnz_budget]
+                with stage("recis.embed.pool"):
+                    rows = vals[ofs: ofs + r.nnz_budget]
+                    out[s.name] = _pool(rows, r, s, use_pallas=use_pallas)
                 ofs += r.nnz_budget
-                out[s.name] = _pool(rows, r, s, use_pallas=use_pallas)
         return out
 
     # ------------------------------------------------------------ update (local)
@@ -217,14 +232,9 @@ class EmbeddingEngine:
         new_state = {}
         for key, g in self.groups.items():
             plan = plans[key]
-            b = apply_row_updates(
-                opt,
-                state_local[key]["blocks"],
-                plan.offsets_r,
-                grads_rows_r[key],
-                plan.valid_r,
-                step,
-            )
+            with stage("recis.sparse.adam"):
+                b = apply_row_updates(opt, state_local[key]["blocks"], plan.offsets_r,
+                                      grads_rows_r[key], plan.valid_r, step)
             new_state[key] = {"idmap": state_local[key]["idmap"], "blocks": b}
         return new_state
 

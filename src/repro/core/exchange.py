@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from repro.core import blocks as blocks_lib
 from repro.core import idmap as idmap_lib
 from repro.core.feature_engine import splitmix64
+from repro.obs.stages import stage
 
 PAD = jnp.int64(-1)
 
@@ -136,6 +137,9 @@ def build_send(
     metrics = {
         "exch_uniq_overflow": ((ids != PAD) & ~ok_val).sum(dtype=jnp.int32),
         "exch_send_overflow": ((owner < D) & ~ok_u).sum(dtype=jnp.int32),
+        # ids in, and left after the dedupe (of U, and of the D*C sent)
+        "exch_ids": (ids != PAD).sum(dtype=jnp.int32),
+        "exch_uniq": (uniq != PAD).sum(dtype=jnp.int32),
     }
     return send, plan, metrics
 
@@ -145,7 +149,11 @@ def owner_merge(recv_ids: jax.Array, spec: ExchangeSpec) -> tuple[jax.Array, jax
     flat = recv_ids.reshape(-1)
     uniq_r, inv_r = unique(flat, spec.recv_budget)
     ok_r = (uniq_r[inv_r] == flat) & (flat != PAD)
-    metrics = {"exch_recv_overflow": ((flat != PAD) & ~ok_r).sum(dtype=jnp.int32)}
+    metrics = {
+        "exch_recv_overflow": ((flat != PAD) & ~ok_r).sum(dtype=jnp.int32),
+        # ids left after the owner merge, of the R rows every row op processes
+        "exch_recv_uniq": (uniq_r != PAD).sum(dtype=jnp.int32),
+    }
     return uniq_r, inv_r, ok_r, metrics
 
 
@@ -163,15 +171,19 @@ def fetch(
     the compact per-owner-unique row matrix — the ONLY tensor the
     differentiable phase depends on.
     """
-    send, plan, met1 = build_send(ids, spec)
+    with stage("recis.exchange.bucket"):
+        send, plan, met1 = build_send(ids, spec)
     if spec.axes and spec.n_devices > 1:
-        recv = jax.lax.all_to_all(send, spec.axes, split_axis=0, concat_axis=0, tiled=True)
+        with stage("recis.exchange.all_to_all"):
+            recv = jax.lax.all_to_all(send, spec.axes, split_axis=0, concat_axis=0, tiled=True)
     else:  # single-device fast path (smoke tests)
         recv = send
-    uniq_r, inv_r, ok_r, met2 = owner_merge(recv, spec)
+    with stage("recis.exchange.owner_merge"):
+        uniq_r, inv_r, ok_r, met2 = owner_merge(recv, spec)
     if train:
         m, offsets_r, is_new, met3 = idmap_lib.lookup_or_insert(m, uniq_r, step)
-        b = blocks_lib.init_rows(b, offsets_r, uniq_r, is_new)
+        with stage("recis.blocks.init_rows"):
+            b = blocks_lib.init_rows(b, offsets_r, uniq_r, is_new)
     else:
         offsets_r = idmap_lib.lookup(m, uniq_r)
         met3 = {}
@@ -180,8 +192,9 @@ def fetch(
     # excluded from updates: several distinct ids share row 0, so training
     # it would accumulate duplicate Adam updates and blow up — graceful
     # degradation instead (the overflow is already counted in metrics).
-    valid_r = (uniq_r != PAD) & (offsets_r != idmap_lib.OVERFLOW_ROW)
-    rows_r = blocks_lib.gather(b, offsets_r) * valid_r[:, None].astype(b.emb.dtype)
+    with stage("recis.blocks.gather"):
+        valid_r = (uniq_r != PAD) & (offsets_r != idmap_lib.OVERFLOW_ROW)
+        rows_r = blocks_lib.gather(b, offsets_r) * valid_r[:, None].astype(b.emb.dtype)
     plan = plan._replace(
         inv_r=inv_r, ok_r=ok_r, offsets_r=offsets_r, valid_r=valid_r
     )
@@ -196,13 +209,12 @@ def route_rows(rows_r: jax.Array, plan: Plan, spec: ExchangeSpec) -> jax.Array:
     """
     D, C = spec.n_devices, spec.per_dest_cap
     dim = rows_r.shape[-1]
-    per_req = rows_r[plan.inv_r] * plan.ok_r[:, None].astype(rows_r.dtype)
-    if spec.axes and spec.n_devices > 1:
-        back = jax.lax.all_to_all(
-            per_req.reshape(D, C, dim), spec.axes, split_axis=0, concat_axis=0, tiled=True
-        )
-    else:
+    with stage("recis.embed.route"):
+        per_req = rows_r[plan.inv_r] * plan.ok_r[:, None].astype(rows_r.dtype)
         back = per_req.reshape(D, C, dim)
-    uniq_rows = back[plan.owner_u, plan.pos_u] * plan.ok_u[:, None].astype(rows_r.dtype)
-    vals = uniq_rows[plan.inv_u] * plan.ok_val[:, None].astype(rows_r.dtype)
-    return vals
+    if spec.axes and spec.n_devices > 1:
+        with stage("recis.exchange.all_to_all"):
+            back = jax.lax.all_to_all(back, spec.axes, split_axis=0, concat_axis=0, tiled=True)
+    with stage("recis.embed.route"):
+        uniq_rows = back[plan.owner_u, plan.pos_u] * plan.ok_u[:, None].astype(rows_r.dtype)
+        return uniq_rows[plan.inv_u] * plan.ok_val[:, None].astype(rows_r.dtype)

@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import write_log
 from repro.core.feature_engine import splitmix64
+from repro.obs.stages import stage
 
 PAD = jnp.int64(-1)
 OVERFLOW_ROW = 0  # blocks row 0 is the reserved collision/overflow bucket
@@ -113,9 +114,17 @@ def _probe_find(keys: jax.Array, occupied: jax.Array, ids: jax.Array,
 
 def lookup(m: IDMap, ids: jax.Array) -> jax.Array:
     """Probe-only. Returns row offsets; missing/pad ids → OVERFLOW_ROW."""
-    found = _probe_find(m.keys, m.occupied, ids, _home(ids, m.capacity),
-                        m.max_probes)
-    return jnp.where(found >= 0, m.offsets[jnp.maximum(found, 0)], OVERFLOW_ROW)
+    with stage("recis.idmap.probe"):
+        found = _probe_find(m.keys, m.occupied, ids, _home(ids, m.capacity),
+                            m.max_probes)
+        return jnp.where(found >= 0, m.offsets[jnp.maximum(found, 0)], OVERFLOW_ROW)
+
+
+def _depth(found: jax.Array, home: jax.Array, mask: jax.Array, cap: int) -> jax.Array:
+    """The last probe round (1-based) in which a ``mask`` id was found or
+    placed: the rounds of the full ``max_probes`` that did useful work. 0
+    when no id is in ``mask``."""
+    return jnp.where(mask, (found - home) % cap + 1, 0).max(initial=0)
 
 
 def lookup_or_insert(
@@ -142,65 +151,79 @@ def _lookup_or_insert_jit(
 ) -> tuple[IDMap, jax.Array, jax.Array, dict]:
     cap = m.capacity
     n = ids.shape[0]
-    home = _home(ids, cap)
-    active = ids != PAD
-    rank = jnp.arange(n, dtype=jnp.int32)
 
     # Pass 1 — find existing keys along the FULL probe chain. This must
     # complete before any empty slot is claimed: after evict/remove cleared
     # a mid-chain slot, claiming it eagerly would duplicate a key that still
     # lives further along (and re-init its row).
-    found = _probe_find(m.keys, m.occupied, ids, home, m.max_probes)
+    with stage("recis.idmap.probe"):
+        home = _home(ids, cap)
+        active = ids != PAD
+        found = _probe_find(m.keys, m.occupied, ids, home, m.max_probes)
+        hit = found >= 0
+        probe_depth = _depth(found, home, hit, cap)
 
     # Pass 2 — only genuinely-missing ids claim empty slots, via scatter-min
     # of batch rank per round (parallel-safe; no atomics on TPU).
-    inserting = active & (found < 0)
+    with stage("recis.idmap.claim"):
+        inserting = active & (found < 0)
+        rank = jnp.arange(n, dtype=jnp.int32)
 
-    def body(r, carry):
-        keys, occ, found = carry
-        slot = (home + r) % cap
-        want = inserting & (found < 0) & ~occ[slot]
-        claims = jnp.full((cap,), n, jnp.int32).at[slot].min(
-            jnp.where(want, rank, n), mode="drop"
+        def body(r, carry):
+            keys, occ, found = carry
+            slot = (home + r) % cap
+            want = inserting & (found < 0) & ~occ[slot]
+            claims = jnp.full((cap,), n, jnp.int32).at[slot].min(
+                jnp.where(want, rank, n), mode="drop"
+            )
+            won = want & (claims[slot] == rank)
+            wslot = jnp.where(won, slot, cap)  # cap = out-of-range → dropped
+            keys = keys.at[wslot].set(ids, mode="drop")
+            occ = occ.at[wslot].set(True, mode="drop")
+            found = jnp.where(won, slot, found)
+            return keys, occ, found
+
+        keys, occ, found = jax.lax.fori_loop(
+            0, m.max_probes, body, (m.keys, m.occupied, found)
         )
-        won = want & (claims[slot] == rank)
-        wslot = jnp.where(won, slot, cap)  # cap = out-of-range → dropped
-        keys = keys.at[wslot].set(ids, mode="drop")
-        occ = occ.at[wslot].set(True, mode="drop")
-        found = jnp.where(won, slot, found)
-        return keys, occ, found
-
-    keys, occ, found = jax.lax.fori_loop(
-        0, m.max_probes, body, (m.keys, m.occupied, found)
-    )
-    is_new = inserting & (found >= 0)
+        is_new = inserting & (found >= 0)
+        claim_depth = _depth(found, home, is_new, cap)
 
     # ---- allocate rows for the winners: recycled offsets first, then bump
-    new_rank = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    n_inserted = is_new.sum(dtype=jnp.int32)
-    from_stack = new_rank < m.free_size
-    stack_idx = jnp.clip(m.free_size - 1 - new_rank, 0, cap - 1)
-    bumped = m.next_row + (new_rank - m.free_size)
-    row = jnp.where(from_stack, m.free_stack[stack_idx], bumped)
-    row_ok = row < m.n_rows
-    row = jnp.where(is_new & row_ok, row, OVERFLOW_ROW).astype(jnp.int32)
+    with stage("recis.idmap.alloc"):
+        new_rank = jnp.cumsum(is_new.astype(jnp.int32)) - 1
+        n_inserted = is_new.sum(dtype=jnp.int32)
+        from_stack = new_rank < m.free_size
+        stack_idx = jnp.clip(m.free_size - 1 - new_rank, 0, cap - 1)
+        bumped = m.next_row + (new_rank - m.free_size)
+        row = jnp.where(from_stack, m.free_stack[stack_idx], bumped)
+        row_ok = row < m.n_rows
+        row = jnp.where(is_new & row_ok, row, OVERFLOW_ROW).astype(jnp.int32)
 
-    taken_from_stack = jnp.minimum(n_inserted, m.free_size)
-    free_size = m.free_size - taken_from_stack
-    next_row = jnp.minimum(
-        m.next_row + jnp.maximum(n_inserted - taken_from_stack, 0), m.n_rows
-    )
+        taken_from_stack = jnp.minimum(n_inserted, m.free_size)
+        free_size = m.free_size - taken_from_stack
+        next_row = jnp.minimum(
+            m.next_row + jnp.maximum(n_inserted - taken_from_stack, 0), m.n_rows
+        )
 
-    offsets = m.offsets.at[jnp.where(is_new, found, cap)].set(row, mode="drop")
-    touched_slot = jnp.where(found >= 0, found, cap)
-    last_use = m.last_use.at[touched_slot].set(step.astype(jnp.int32), mode="drop")
+        offsets = m.offsets.at[jnp.where(is_new, found, cap)].set(row, mode="drop")
+        touched_slot = jnp.where(found >= 0, found, cap)
+        last_use = m.last_use.at[touched_slot].set(step.astype(jnp.int32), mode="drop")
 
-    out_off = jnp.where(found >= 0, offsets[jnp.maximum(found, 0)], OVERFLOW_ROW)
-    metrics = {
-        "idmap_inserted": n_inserted,
-        "idmap_probe_overflow": (active & (found < 0)).sum(dtype=jnp.int32),
-        "idmap_row_overflow": (is_new & ~row_ok).sum(dtype=jnp.int32),
-    }
+        out_off = jnp.where(found >= 0, offsets[jnp.maximum(found, 0)], OVERFLOW_ROW)
+        metrics = {
+            "idmap_inserted": n_inserted,
+            "idmap_probe_overflow": (active & (found < 0)).sum(dtype=jnp.int32),
+            "idmap_row_overflow": (is_new & ~row_ok).sum(dtype=jnp.int32),
+            # useful work of the two probe passes: each runs all
+            # ``idmap_rounds``; the depths are the rounds that found or
+            # placed an id (per chip: reduced with a max, the rest summed)
+            "idmap_rounds": jnp.int32(m.max_probes),
+            "idmap_probe_depth": probe_depth,
+            "idmap_claim_depth": claim_depth,
+            "idmap_lookups": active.sum(dtype=jnp.int32),
+            "idmap_hits": hit.sum(dtype=jnp.int32),
+        }
     new_m = IDMap(
         keys=keys, occupied=occ, offsets=offsets, last_use=last_use,
         free_stack=m.free_stack, free_size=free_size, next_row=next_row,

@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.core import exchange
-from repro.core.embedding_engine import EmbeddingEngine, EngineConfig
+from repro.core.embedding_engine import EmbeddingEngine, EngineConfig, reduce_metrics
 from repro.core.feature_engine import FeatureSpec
 from repro.io.ragged import Ragged
 from repro.launch.common import Cell, CellOptions, abstractify, mesh_info, round_up
@@ -59,7 +59,7 @@ def _fetch_sm(engine: EmbeddingEngine, gkey: str, mesh, axes, ids_spec, L_local,
         # row structure is irrelevant for pooling="values": one row holds all ids.
         ragged = Ragged(flat, jnp.array([0, L_local], jnp.int32))
         st, rows_r, plans, met = engine.fetch_local(st, {"tokens": ragged}, step, train=train)
-        met = jax.lax.psum(met, axes)
+        met = reduce_metrics(met, axes)
         return (jax.tree.map(lambda x: x[None], st), rows_r[gkey], plans[gkey], met)
 
     return shard_map(

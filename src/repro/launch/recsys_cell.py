@@ -19,11 +19,12 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeCell
-from repro.core.embedding_engine import EmbeddingEngine, EngineConfig
+from repro.core.embedding_engine import EmbeddingEngine, EngineConfig, reduce_metrics
 from repro.core.feature_engine import FeatureEngine, FeatureSpec
 from repro.io.ragged import Ragged
 from repro.launch.common import Cell, CellOptions, abstractify, mesh_info, round_up
 from repro.models.layers import MIXED
+from repro.obs.stages import stage
 from repro.optim import adamw
 from repro.optim.sparse_adam import SparseAdamConfig
 from jax import shard_map
@@ -199,9 +200,10 @@ def build(arch: ArchConfig, shape: ShapeCell, mesh, opts: CellOptions = CellOpti
 
     def fetch_fn(sp_state, batch, step):
         st = jax.tree.map(lambda x: x[0], sp_state)
-        ids, _ = pl.prepared(_split_local(pl, batch))
+        with stage("recis.ids.hash"):
+            ids, _ = pl.prepared(_split_local(pl, batch))
         st, rows_r, plans, met = pl.engine.fetch_local(st, ids, step, train=train and opts.train_insert)
-        met = jax.lax.psum(met, axes)
+        met = reduce_metrics(met, axes)
         return (jax.tree.map(lambda x: x[None], st),
                 tuple(rows_r[k] for k in gkeys), tuple(plans[k] for k in gkeys), met)
 
@@ -260,11 +262,13 @@ def build(arch: ArchConfig, shape: ShapeCell, mesh, opts: CellOptions = CellOpti
 
             def loss_fn(dense_params, rows_r):
                 acts = route(rows_r, plans, batch)
-                return model.loss(dense_params, mcfg, acts, dense_feats, MIXED)
+                with stage("recis.tower"):
+                    return model.loss(dense_params, mcfg, acts, dense_feats, MIXED)
 
             loss, (gdense, grows) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
                 state["dense"], rows_r)
-            new_dense, new_opt = adamw.update(acfg, state["dense"], gdense, state["opt"], step)
+            with stage("recis.dense.adamw"):
+                new_dense, new_opt = adamw.update(acfg, state["dense"], gdense, state["opt"], step)
             new_sparse = update(new_sparse, plans, grows, step)
             return ({"step": step, "dense": new_dense, "opt": new_opt, "sparse": new_sparse},
                     {"loss": loss, **met})
@@ -324,7 +328,7 @@ def _build_retrieval(arch: ArchConfig, shape: ShapeCell, mesh, opts: CellOptions
         st_c, rows_c, plans_c, met2 = pl_c.engine.fetch_local(st_c, ids_c, step, train=False)
         acts_u = pl_u.engine.activations(rows_u, plans_u, ids_u, use_pallas=opts.use_pallas)
         acts_c = pl_c.engine.activations(rows_c, plans_c, ids_c, use_pallas=opts.use_pallas)
-        met = jax.lax.psum({**met1, **met2}, axes)
+        met = reduce_metrics({**met1, **met2}, axes)
         return acts_u, acts_c, met
 
     acts_u_specs = {s.name: P(None) for s in user_specs if s.emb_dim is not None}

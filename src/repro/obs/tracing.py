@@ -18,7 +18,9 @@ one JSONL ``step`` record and folded into the registry's ``trace/<name>_s``
 histograms. Spans outside a step (the final checkpoint) emit standalone
 ``span`` records. With ``profile=True`` each span additionally opens a
 ``jax.profiler.TraceAnnotation`` so the phases show up in TensorBoard /
-Perfetto traces next to XLA's own events.
+Perfetto traces next to XLA's own events, and each step opens a
+``jax.profiler.StepTraceAnnotation("train", step_num=n)``, the profiler's
+own step marker.
 
 At 1,500+-accelerator scale this is what makes stragglers diagnosable:
 the watchdog consumes ``StepTrace.spans`` and reports *which phase* was
@@ -90,8 +92,14 @@ class Tracer:
     def step(self, step: int) -> Iterator[StepTrace]:
         st = StepTrace(step)
         prev, self._current = self._current, st
+        if self.profile:  # the profiler's own step marker, beside the phase spans
+            from jax.profiler import StepTraceAnnotation
+            prof = StepTraceAnnotation("train", step_num=step)
+        else:
+            prof = contextlib.nullcontext()
         try:
-            yield st
+            with prof:
+                yield st
         finally:
             self._current = prev
             if not st.cancelled and self.writer is not None:

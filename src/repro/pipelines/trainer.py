@@ -453,8 +453,9 @@ class Trainer:
                         c_straggler.inc()
                     if self.anomaly is not None:
                         self.anomaly.observe_step(step, st.spans)
-                    m_scalar = {k: float(np.asarray(v)) for k, v in metrics.items()
-                                if np.ndim(v) == 0}
+                    # one transfer for all of the step's scalars, not one each
+                    m_scalar = {k: float(v) for k, v in jax.device_get(
+                        {k: v for k, v in metrics.items() if np.ndim(v) == 0}).items()}
                     st.annotate(wall_s=dt, straggler=bool(slow), metrics=m_scalar)
                     if slow and self.watchdog.events:
                         st.annotate(straggler_phase=self.watchdog.events[-1].phase)

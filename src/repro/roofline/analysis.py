@@ -292,7 +292,9 @@ def model_flops(arch, shape) -> float:
     if fam == "recsys":
         b = shape.get("batch", 1)
         m = arch.model
-        mults = {"train": 6.0, "serve": 2.0, "retrieval": 2.0}[shape.kind]
+        # per_ex is a forward pass at 2 FLOPs per MAC; training adds the
+        # backward's two matmuls per forward one
+        mults = {"train": 3.0, "serve": 1.0, "retrieval": 1.0}[shape.kind]
         per_ex = _recsys_dense_flops(arch.arch_id, m)
         if shape.kind == "retrieval":
             b = shape["n_candidates"]
@@ -353,19 +355,3 @@ def flash_attention_cost(b_loc: int, t: int, h_loc: int, hk_loc: int, hd: int,
     mult_f = 4.0 if train else 1.0
     mult_b = 3.0 if train else 1.0
     return {"flops": mult_f * fwd_flops, "bytes": mult_b * fwd_bytes}
-
-
-# ---------------------------------------------------------------------------
-# sparse-path MBU traffic model (paper Table-1 style per-op accounting)
-# ---------------------------------------------------------------------------
-
-def sparse_traffic_bytes(n_ids: int, dim: int, dtype_bytes: int = 4) -> dict:
-    """Minimal HBM traffic for one embedding fetch+update of n_ids rows —
-    the denominator-side of the paper's MBU for sparse ops."""
-    row = dim * dtype_bytes
-    return {
-        "gather": n_ids * (row + 8),                    # rows + ids
-        "scatter_update": n_ids * (3 * row * 2 + 8),    # read+write emb,m,v
-        "unique_sort": n_ids * 8 * 4,                   # ~2 passes of 64-bit sort
-        "segment_reduce": n_ids * row + 8 * n_ids,
-    }

@@ -11,6 +11,7 @@ one process at a time may load the TPU library, and every test worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ from repro.kernels.segment_reduce import ops as sr_ops
 from repro.launch.cells import build_cell
 from repro.launch.common import CellOptions
 from repro.launch.mesh import make_mesh
+from repro.obs.stages import STAGES, backward_of, op_names, stage_of
 from repro.pipelines import TrainConfig, Trainer
 from repro.roofline.analysis import chip_peaks
 
@@ -113,3 +115,54 @@ def test_dlrm_published_train_step_fits_one_chip(topo):
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > rows * 128 * 4 * 3  # emb, m, v
     assert held < chip_peaks("TPU v5 lite").hbm_bytes, held
+
+
+_SPARSE_OPS = ("sort", "scatter", "gather", "all-to-all")
+
+
+def _sparse_ops(hlo_text: str) -> set[str]:
+    """Every sort, scatter, gather and all-to-all instruction, and every
+    fusion that holds one (at any depth)."""
+    comps, body = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%(\S+) .*\{\s*$", line)
+        if head and " = " not in line:
+            body = comps.setdefault(head.group(1), [])
+            continue
+        name = re.match(r"\s*(?:ROOT\s+)?%([^\s=]+) = ", line)
+        if body is None or name is None:
+            continue
+        op = re.search(r"\s([a-z][a-z0-9-]*)\(%", line)
+        called = re.search(r"calls=%([\w.\-]+)", line)
+        body.append((name.group(1), op and op.group(1), called and called.group(1)))
+
+    def holds(comp):
+        return any(op in _SPARSE_OPS or (op == "fusion" and holds(c))
+                   for _, op, c in comps.get(comp, []))
+
+    return {n for body in comps.values() for n, op, c in body
+            if op in _SPARSE_OPS or (op == "fusion" and holds(c))}
+
+
+def test_every_sparse_op_of_the_sharded_step_names_one_stage(topo):
+    """The dlrm train step on the four described chips, at a size that
+    compiles in well under a minute: each sort, scatter, gather and
+    all-to-all, and each fusion holding one, names exactly one stage in its
+    op_name; every stage appears; route and the all-to-all appear in both
+    directions."""
+    assert len(topo.devices) == 4
+    mesh = make_mesh(devices=topo.devices)
+    shape = ShapeCell("train_batch", "train", {"batch": 512})
+    cell = build_cell("dlrm-mlperf", "train_batch", mesh,
+                      CellOptions(remat=False, zero1=False, chip_table_rows=1024),
+                      shape_override=shape)
+    text = Trainer(cell, TrainConfig(watchdog=False)).compiled().as_text()
+    names = op_names(text)
+    ops = _sparse_ops(text)
+    assert len(ops) > 100
+    named = {n: set(re.findall(r"recis(?:\.\w+)+", names[n])) for n in ops}
+    assert {n: s for n, s in named.items() if len(s) != 1 or not s <= set(STAGES)} == {}
+    assert {stage_of(n) for n in names.values()} >= set(STAGES)
+    both = {(stage_of(names[n]), backward_of(names[n])) for n in ops}
+    for s in ("recis.embed.route", "recis.exchange.all_to_all"):
+        assert {(s, False), (s, True)} <= both

@@ -181,6 +181,32 @@ class TestIDMap:
         # recycled rows reused (free-stack pop)
         assert set(np.asarray(off2).tolist()) == set(np.asarray(off1).tolist())
 
+    def test_probe_and_claim_depths_count_useful_rounds(self):
+        """Planted collisions: three ids homed on the last slot wrap to
+        slots 0 and 1 (claimed in rounds 1, 2, 3), and an id homed on slot
+        0 then finds 0 and 1 taken (round 3). Both passes run all 32
+        rounds; the depths say how many did work."""
+        cap = 64
+        cand = jnp.arange(1, 4096, dtype=jnp.int64)
+        home = np.asarray(idmap_lib._home(cand, cap))
+        last = np.asarray(cand)[home == cap - 1][:3]
+        first = np.asarray(cand)[home == 0][:1]
+        m = idmap_lib.create(cap, 32)
+
+        m, _, _, met = idmap_lib.lookup_or_insert(m, jnp.asarray(last), jnp.int32(1))
+        assert int(met["idmap_rounds"]) == 32
+        assert (int(met["idmap_probe_depth"]), int(met["idmap_claim_depth"])) == (0, 3)
+        assert (int(met["idmap_lookups"]), int(met["idmap_hits"])) == (3, 0)
+
+        m, _, _, met = idmap_lib.lookup_or_insert(m, jnp.asarray(first), jnp.int32(2))
+        assert (int(met["idmap_probe_depth"]), int(met["idmap_claim_depth"])) == (0, 3)
+
+        both = jnp.asarray(np.concatenate([last, first, [-1, -1]]), jnp.int64)
+        m, _, new, met = idmap_lib.lookup_or_insert(m, both, jnp.int32(3))
+        assert not bool(new.any())
+        assert (int(met["idmap_probe_depth"]), int(met["idmap_claim_depth"])) == (3, 0)
+        assert (int(met["idmap_lookups"]), int(met["idmap_hits"])) == (4, 4)
+
     @settings(max_examples=15, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1))
     def test_idempotent_reinsert(self, seed):
@@ -259,6 +285,17 @@ class TestExchange:
         live = gsum[valid[: len(gsum)]] if valid.any() else gsum
         assert set(np.round(gsum[gsum != 0]).astype(int).tolist()) == {16, 4}
         # 16 = id5: two slots × 2.0 × dim4; 4 = id9: one slot × 1.0 × dim4
+
+    @pytest.mark.parametrize("u, want", [(32, (5, 3, 3)), (3, (5, 2, 2))])
+    def test_exchange_counts_ids_at_each_boundary(self, u, want):
+        """Active ids in, after the requester dedupe (at most U, of which
+        a PAD in the batch takes one), after the owner merge; PAD is never
+        counted."""
+        m = idmap_lib.create(256, 128)
+        b = blocks_lib.create(128, 4)
+        ids = jnp.asarray([5, 5, 7, -1, 9, 7], jnp.int64)
+        *_, met = exchange.fetch(m, b, ids, _spec(u=u, c=u, r=u), jnp.int32(1), True)
+        assert tuple(int(met[k]) for k in ("exch_ids", "exch_uniq", "exch_recv_uniq")) == want
 
     def test_overflow_counted_not_silent(self, rng):
         spec = _spec(u=8, c=8, r=8)

@@ -107,3 +107,13 @@ def test_chip_smoke_refuses_cpu_and_prints_no_verdict():
     for line in r.stdout.splitlines():
         with pytest.raises(json.JSONDecodeError):
             json.loads(line)
+
+
+@pytest.mark.parametrize("kind, mflop", [("train", 14.75), ("serve", 4.917)])
+def test_dlrm_model_flops_per_example(kind, mflop):
+    """A forward pass at 2 FLOPs per MAC (bottom MLP, dot interaction, top
+    MLP), three of them to train: 14.75 MFLOP per example."""
+    from repro.configs import get_config
+
+    flops = ra.model_flops(get_config("dlrm-mlperf"), ShapeCell("x", kind, {"batch": 1}))
+    assert flops / 1e6 == pytest.approx(mflop, rel=1e-4)

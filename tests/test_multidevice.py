@@ -85,6 +85,52 @@ class TestExchangeMultiDevice:
             print("EXCHANGE_OK")
         """)
 
+    def test_counters_sum_counts_and_max_depths_across_chips(self):
+        """On 4 devices, the engine's metrics leave shard_map as the sum of
+        each device's counts and the max of its rounds and depths."""
+        out = run_sub("""
+            from repro.core.embedding_engine import (
+                EmbeddingEngine, EngineConfig, MAX_METRICS, reduce_metrics)
+            from repro.core.feature_engine import FeatureSpec
+            from repro.io.ragged import Ragged
+            from repro.launch.mesh import make_mesh
+
+            mesh = make_mesh((4,), ("data",))
+            specs = [FeatureSpec("f", transform="hash", emb_dim=4, pooling="sum")]
+            # a small map per device, so that probe chains grow
+            eng = EmbeddingEngine(specs, EngineConfig(
+                mesh_axes=("data",), n_devices=4, rows_per_shard=128,
+                map_capacity_per_shard=128, u_budget=64, per_dest_cap=64,
+                recv_budget=128))
+            r = np.random.default_rng(0)
+            vals = jnp.asarray(r.integers(0, 1 << 40, 4 * 48), jnp.int64)
+            splits = jnp.tile(jnp.arange(49, dtype=jnp.int32), 4)
+            sp = P("data")
+
+            def step(sp_state, vals, splits):
+                st = jax.tree.map(lambda x: x[0], sp_state)
+                _, _, _, met = eng.fetch_local(st, {"f": Ragged(vals, splits)},
+                                               jnp.int32(1))
+                return ({k: v[None] for k, v in met.items()},
+                        reduce_metrics(met, ("data",)))
+
+            per, red = jax.jit(shard_map(
+                step, mesh=mesh, in_specs=(sp, sp, sp), out_specs=(sp, P()),
+                check_vma=False))(eng.init_state(), vals, splits)
+            assert set(red) == set(per)
+            for k, v in red.items():
+                each = np.asarray(per[k])
+                want = each.max() if k.split("/")[-1] in MAX_METRICS else each.sum()
+                assert int(v) == int(want), (k, v, each)
+            depth = np.asarray(per["dim4/idmap_claim_depth"])
+            assert depth.max() > 1 and depth.sum() > depth.max()
+            assert int(red["dim4/idmap_rounds"]) == 32
+            assert int(red["dim4/exch_ids"]) == 4 * 48
+            assert int(red["dim4/idmap_inserted"]) == int(red["dim4/exch_recv_uniq"])
+            print("COUNTERS_OK", depth.tolist())
+        """, n_dev=4)
+        assert "COUNTERS_OK" in out
+
     def test_grad_update_consistency(self):
         """Sharded update: a second fetch sees the updated rows (train cycle)."""
         run_sub("""

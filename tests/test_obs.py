@@ -6,12 +6,15 @@ telemetry-enabled Trainer emits a parseable phase-attributed JSONL trace
 with storage/IO counters under unified names."""
 import os
 import signal
+import textwrap
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import obs
+from repro.obs.stages import STAGES, backward_of, op_names, stage, stage_of
 from repro.pipelines import (
     PreemptionGuard, StragglerWatchdog, TrainConfig, Trainer,
 )
@@ -150,6 +153,82 @@ class TestTracer:
         recs = obs.read_jsonl(tmp_path / "t.jsonl")
         assert len(recs) == 1 and recs[0]["type"] == "span"
         assert recs[0]["name"] == "checkpoint"
+
+
+class TestProfilerStepMarker:
+    def test_profiled_steps_open_the_profilers_step_marker(self, monkeypatch):
+        opened = []
+
+        class Marker:
+            def __init__(self, name, **kw):
+                opened.append((name, kw))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Marker)
+        with obs.Tracer(profile=True).step(7):
+            pass
+        with obs.Tracer().step(8):  # unprofiled: no marker
+            pass
+        assert opened == [("train", {"step_num": 7})]
+
+
+# ---------------------------------------------------------------------------
+# stage scopes of the train step (repro.obs.stages)
+# ---------------------------------------------------------------------------
+
+class TestStages:
+    def test_stages_are_dotted_recis_names_and_unknown_ones_raise(self):
+        assert len(set(STAGES)) == len(STAGES)
+        assert all(stage_of(f"jit(f)/{s}/add") == s for s in STAGES)
+        with pytest.raises(ValueError, match="not a stage"):
+            stage("recis.idmap.nothing")
+
+    def test_stage_of_is_the_innermost_and_backward_of_the_direction(self):
+        fwd = "jit(step_fn)/jvp()/shard_map/recis.embed.route/gather"
+        bwd = "jit(step_fn)/transpose(jvp())/shard_map/recis.embed.route/scatter-add"
+        inner = "jit(f)/recis.embed.route/recis.exchange.all_to_all/all_to_all"
+        assert stage_of(fwd) == stage_of(bwd) == "recis.embed.route"
+        assert not backward_of(fwd) and backward_of(bwd)
+        assert stage_of(inner) == "recis.exchange.all_to_all"
+        assert stage_of("jit(step_fn)/shard_map/add") is None
+
+    def test_scope_reaches_the_compiled_program_in_both_directions(self):
+        def loss(x, i):
+            with stage("recis.blocks.gather"):
+                return (x[i] * 2.0).sum()
+
+        text = jax.jit(jax.grad(loss)).lower(jnp.ones(8), jnp.arange(3)).compile().as_text()
+        seen = {(stage_of(n), backward_of(n)) for n in op_names(text).values()}
+        assert {("recis.blocks.gather", False), ("recis.blocks.gather", True)} <= seen
+
+    def test_op_names_fill_in_instructions_the_compiler_made(self):
+        """A scatter the compiler rebuilt without metadata takes its fused
+        computation's stage, and so does the fusion that calls it."""
+        names = op_names(textwrap.dedent("""\
+            %fused_computation.1 (p0: f32[8], p1: s32[2], p2: f32[2]) -> f32[8] {
+              %p0 = f32[8]{0} parameter(0)
+              %p1 = s32[2]{0} parameter(1)
+              %p2 = f32[2]{0} parameter(2)
+              %t = f32[2]{0} transpose(%p2), dimensions={0}, metadata={op_name="jit(f)/transpose(jvp())/recis.embed.route/mul"}
+              ROOT %scatter.1 = f32[8]{0} scatter(%p0, %p1, %t), to_apply=%add
+            }
+
+            ENTRY %main (x: f32[8], i: s32[2], u: f32[2]) -> f32[8] {
+              %x = f32[8]{0} parameter(0)
+              %i = s32[2]{0} parameter(1)
+              %u = f32[2]{0} parameter(2)
+              %fusion.1 = f32[8]{0} fusion(%x, %i, %u), kind=kCustom, calls=%fused_computation.1
+              ROOT %copy.1 = f32[8]{0} copy(%fusion.1)
+            }
+            """))
+        assert stage_of(names["scatter.1"]) == stage_of(names["fusion.1"]) == "recis.embed.route"
+        assert backward_of(names["fusion.1"])
+        assert names["copy.1"] == ""  # outside any fusion: nothing to inherit
 
 
 class TestSpanNamespace:
@@ -294,6 +373,39 @@ class _CountingHooks:
 
     def post_step(self, state, step):
         return state, {"storage/admission_demoted": 1}
+
+
+class _MetricsCell:
+    returns_state = True
+    donate_state = False
+
+    @staticmethod
+    def step_fn(state, batch):
+        w = state["w"] + 1.0
+        return {"w": w}, {"loss": w * 0.5, "g/idmap_hits": (3 * w).astype(jnp.int32),
+                          "rows": jnp.arange(3.0)}
+
+
+class TestStepReadout:
+    def test_scalar_metrics_leave_the_device_in_one_transfer(self, monkeypatch):
+        real, pulls = jax.device_get, []
+
+        def spy(tree):
+            if isinstance(tree, dict):  # as each scalar was read one by one
+                pulls.append({k: float(np.asarray(v)) for k, v in tree.items()})
+            return real(tree)
+
+        monkeypatch.setattr(jax, "device_get", spy)
+        tr = Trainer(_MetricsCell(), TrainConfig(total_steps=3, log_every=1,
+                                                 watchdog=False),
+                     registry=obs.MetricsRegistry())
+        res = tr.run({"w": jnp.zeros(())}, iter(range(3)))
+        assert len(pulls) == 3  # one transfer per step
+        for row, one_by_one in zip(res.metrics_history, pulls):
+            assert {k: row[k] for k in one_by_one} == one_by_one
+            assert "rows" not in row  # not a scalar
+        assert [r["loss"] for r in res.metrics_history] == [0.5, 1.0, 1.5]
+        assert [r["g/idmap_hits"] for r in res.metrics_history] == [3.0, 6.0, 9.0]
 
 
 class TestIntervalAccumulation:
