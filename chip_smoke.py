@@ -103,6 +103,8 @@ def report(label: str, rows, device) -> list[float]:
               f"dev_rows_live={m[f'{G}/dev_rows_live']:.0f} "
               f"idmap_probe_depth={m[f'{G}/idmap_probe_depth']:.0f} "
               f"idmap_claim_depth={m[f'{G}/idmap_claim_depth']:.0f} "
+              f"idmap_probe_rounds={m[f'{G}/idmap_probe_rounds']:.0f} "
+              f"idmap_claim_rounds={m[f'{G}/idmap_claim_rounds']:.0f} "
               f"idmap_rounds={m[f'{G}/idmap_rounds']:.0f} "
               f"compiles={m['compiles']:.0f} compile_s={m['compile_s']:.1f} "
               f"wall_s={m['wall_s']:.3f}", flush=True)

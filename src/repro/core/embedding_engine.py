@@ -41,9 +41,11 @@ from repro.storage.tiered import StorageConfig, TieredEmbeddingStore
 
 PAD = jnp.int64(-1)
 
-# per-chip metrics that are maxima, not counts: a probe pass runs
-# ``idmap_rounds`` on every chip, and the depths are the deepest round used
-MAX_METRICS = ("idmap_rounds", "idmap_probe_depth", "idmap_claim_depth")
+# per-chip metrics that are maxima, not counts: ``idmap_rounds`` is each
+# probe pass's budget on every chip, the ``*_rounds`` the rounds a pass ran,
+# and the depths the deepest round used
+MAX_METRICS = ("idmap_rounds", "idmap_probe_rounds", "idmap_claim_rounds",
+               "idmap_probe_depth", "idmap_claim_depth")
 
 
 def reduce_metrics(metrics: Mapping[str, jax.Array], axes) -> dict:

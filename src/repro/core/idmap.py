@@ -10,6 +10,13 @@ unlike static `id % vocab` tables. Collisions only exhaust after
 ids fall back to the reserved overflow row 0 and are **counted**, never
 dropped silently.
 
+Deletes (evict / remove) leave no tombstones. A probe instead scans every
+round up to ``max_depth``, the deepest probe distance at which any key was
+ever placed in the table, with no early-out on an empty slot: no stored key
+lies further from its home, so a slot cleared mid-chain cannot hide one.
+The claim loop runs while an inserting id is still unplaced. Both loops stop
+at ``max_probes`` at the latest.
+
 All operations are jit-compatible, vectorized, and run fully on-device:
   lookup            pure probe (serving path)
   lookup_or_insert  probe + parallel claim of empty slots (training path)
@@ -51,13 +58,14 @@ class IDMap:
     free_stack: jax.Array  # (capacity,) int32 — recycled row offsets
     free_size: jax.Array   # () int32
     next_row: jax.Array    # () int32 — bump allocator (row 0 reserved)
+    max_depth: jax.Array   # () int32 — deepest 1-based probe distance ever placed
     n_rows: int            # static: Blocks row capacity
     max_probes: int        # static
 
     def tree_flatten(self):
         children = (
             self.keys, self.occupied, self.offsets, self.last_use,
-            self.free_stack, self.free_size, self.next_row,
+            self.free_stack, self.free_size, self.next_row, self.max_depth,
         )
         return children, (self.n_rows, self.max_probes)
 
@@ -82,6 +90,7 @@ def create(capacity: int, n_rows: int, max_probes: int = 32) -> IDMap:
         free_stack=jnp.zeros((capacity,), jnp.int32),
         free_size=jnp.zeros((), jnp.int32),
         next_row=jnp.ones((), jnp.int32),  # row 0 reserved for overflow
+        max_depth=jnp.zeros((), jnp.int32),
         n_rows=n_rows,
         max_probes=max_probes,
     )
@@ -91,13 +100,19 @@ def _home(ids: jax.Array, capacity: int) -> jax.Array:
     return (splitmix64(ids) % jnp.uint64(capacity)).astype(jnp.int32)
 
 
-def _probe_find(keys: jax.Array, occupied: jax.Array, ids: jax.Array,
-                home: jax.Array, max_probes: int) -> jax.Array:
-    """Slot of each id along its full probe chain, -1 when absent.
+def _probe_rounds(m: IDMap) -> jax.Array:
+    """Rounds a probe of ``m`` runs: no key lies beyond ``max_depth``."""
+    return jnp.minimum(m.max_depth, m.max_probes)
 
-    Probes ALL ``max_probes`` rounds with no early-out on empty slots, so
-    deletions (evict / remove) need no tombstones: a cleared slot mid-chain
-    cannot hide a key stored further along.
+
+def _probe_find(keys: jax.Array, occupied: jax.Array, ids: jax.Array,
+                home: jax.Array, rounds: jax.Array) -> jax.Array:
+    """Slot of each id along its probe chain, -1 when absent.
+
+    Probes all ``rounds`` (``_probe_rounds``: every distance at which a key
+    was ever placed) with no early-out on empty slots, so deletions (evict /
+    remove) need no tombstones: a cleared slot mid-chain cannot hide a key
+    stored further along, and no key is stored beyond ``max_depth``.
     """
     cap = keys.shape[0]
     active = ids != PAD
@@ -109,21 +124,20 @@ def _probe_find(keys: jax.Array, occupied: jax.Array, ids: jax.Array,
         hit = need & occupied[slot] & (keys[slot] == ids)
         return jnp.where(hit, slot, found)
 
-    return jax.lax.fori_loop(0, max_probes, body, found)
+    return jax.lax.fori_loop(0, rounds, body, found)
 
 
 def lookup(m: IDMap, ids: jax.Array) -> jax.Array:
     """Probe-only. Returns row offsets; missing/pad ids → OVERFLOW_ROW."""
     with stage("recis.idmap.probe"):
         found = _probe_find(m.keys, m.occupied, ids, _home(ids, m.capacity),
-                            m.max_probes)
+                            _probe_rounds(m))
         return jnp.where(found >= 0, m.offsets[jnp.maximum(found, 0)], OVERFLOW_ROW)
 
 
 def _depth(found: jax.Array, home: jax.Array, mask: jax.Array, cap: int) -> jax.Array:
     """The last probe round (1-based) in which a ``mask`` id was found or
-    placed: the rounds of the full ``max_probes`` that did useful work. 0
-    when no id is in ``mask``."""
+    placed: the rounds that did useful work. 0 when no id is in ``mask``."""
     return jnp.where(mask, (found - home) % cap + 1, 0).max(initial=0)
 
 
@@ -152,25 +166,31 @@ def _lookup_or_insert_jit(
     cap = m.capacity
     n = ids.shape[0]
 
-    # Pass 1 — find existing keys along the FULL probe chain. This must
-    # complete before any empty slot is claimed: after evict/remove cleared
-    # a mid-chain slot, claiming it eagerly would duplicate a key that still
-    # lives further along (and re-init its row).
+    # Pass 1 — find existing keys along the whole probe chain (to
+    # ``max_depth``). This must complete before any empty slot is claimed:
+    # after evict/remove cleared a mid-chain slot, claiming it eagerly would
+    # duplicate a key that still lives further along (and re-init its row).
     with stage("recis.idmap.probe"):
         home = _home(ids, cap)
         active = ids != PAD
-        found = _probe_find(m.keys, m.occupied, ids, home, m.max_probes)
+        probe_rounds = _probe_rounds(m)
+        found = _probe_find(m.keys, m.occupied, ids, home, probe_rounds)
         hit = found >= 0
         probe_depth = _depth(found, home, hit, cap)
 
     # Pass 2 — only genuinely-missing ids claim empty slots, via scatter-min
-    # of batch rank per round (parallel-safe; no atomics on TPU).
+    # of batch rank per round (parallel-safe; no atomics on TPU). It stops
+    # once every inserting id holds a slot: later rounds could place none.
     with stage("recis.idmap.claim"):
         inserting = active & (found < 0)
         rank = jnp.arange(n, dtype=jnp.int32)
 
-        def body(r, carry):
-            keys, occ, found = carry
+        def cond(carry):
+            r, _, _, found = carry
+            return (r < m.max_probes) & jnp.any(inserting & (found < 0))
+
+        def body(carry):
+            r, keys, occ, found = carry
             slot = (home + r) % cap
             want = inserting & (found < 0) & ~occ[slot]
             claims = jnp.full((cap,), n, jnp.int32).at[slot].min(
@@ -181,10 +201,10 @@ def _lookup_or_insert_jit(
             keys = keys.at[wslot].set(ids, mode="drop")
             occ = occ.at[wslot].set(True, mode="drop")
             found = jnp.where(won, slot, found)
-            return keys, occ, found
+            return r + 1, keys, occ, found
 
-        keys, occ, found = jax.lax.fori_loop(
-            0, m.max_probes, body, (m.keys, m.occupied, found)
+        claim_rounds, keys, occ, found = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), m.keys, m.occupied, found)
         )
         is_new = inserting & (found >= 0)
         claim_depth = _depth(found, home, is_new, cap)
@@ -215,10 +235,13 @@ def _lookup_or_insert_jit(
             "idmap_inserted": n_inserted,
             "idmap_probe_overflow": (active & (found < 0)).sum(dtype=jnp.int32),
             "idmap_row_overflow": (is_new & ~row_ok).sum(dtype=jnp.int32),
-            # useful work of the two probe passes: each runs all
-            # ``idmap_rounds``; the depths are the rounds that found or
-            # placed an id (per chip: reduced with a max, the rest summed)
+            # work of the two probe passes: ``idmap_rounds`` is the budget,
+            # the ``*_rounds`` are the rounds each pass ran, the depths the
+            # last round that found or placed an id (per chip: reduced with
+            # a max, the rest summed)
             "idmap_rounds": jnp.int32(m.max_probes),
+            "idmap_probe_rounds": probe_rounds,
+            "idmap_claim_rounds": claim_rounds,
             "idmap_probe_depth": probe_depth,
             "idmap_claim_depth": claim_depth,
             "idmap_lookups": active.sum(dtype=jnp.int32),
@@ -227,6 +250,7 @@ def _lookup_or_insert_jit(
     new_m = IDMap(
         keys=keys, occupied=occ, offsets=offsets, last_use=last_use,
         free_stack=m.free_stack, free_size=free_size, next_row=next_row,
+        max_depth=jnp.maximum(m.max_depth, claim_depth),
         n_rows=m.n_rows, max_probes=m.max_probes,
     )
     return new_m, out_off, is_new & row_ok, metrics
@@ -238,14 +262,16 @@ def remove(m: IDMap, ids: jax.Array) -> tuple[IDMap, jax.Array, jax.Array]:
     The demotion primitive of the tiered store (DESIGN.md §4): the caller
     gathers the rows at the returned offsets BEFORE dropping its reference
     to the old Blocks, then spills them to the host tier. Probe-chain safety
-    relies on ``_probe_find`` scanning all ``max_probes`` rounds, so no
-    tombstone is needed. Returns (new_map, offsets, found_mask); offsets of
-    missing/pad ids are OVERFLOW_ROW.
+    relies on ``_probe_find`` scanning every round to ``max_depth``, which a
+    cleared slot leaves as it is, so no tombstone is needed. Returns
+    (new_map, offsets, found_mask); offsets of missing/pad ids are
+    OVERFLOW_ROW.
 
     ids MUST be unique up to PAD padding (same contract as insert).
     """
     cap = m.capacity
-    found = _probe_find(m.keys, m.occupied, ids, _home(ids, cap), m.max_probes)
+    found = _probe_find(m.keys, m.occupied, ids, _home(ids, cap),
+                        _probe_rounds(m))
     found_mask = found >= 0
     offs = m.offsets[jnp.maximum(found, 0)]
     occupied = m.occupied.at[jnp.where(found_mask, found, cap)].set(
@@ -267,6 +293,7 @@ def remove(m: IDMap, ids: jax.Array) -> tuple[IDMap, jax.Array, jax.Array]:
         free_stack=free_stack,
         free_size=jnp.minimum(m.free_size + n_freed, cap),
         next_row=m.next_row,
+        max_depth=m.max_depth,
         n_rows=m.n_rows,
         max_probes=m.max_probes,
     )
@@ -299,6 +326,7 @@ def evict(m: IDMap, older_than: jax.Array) -> tuple[IDMap, jax.Array]:
         free_stack=free_stack,
         free_size=jnp.minimum(m.free_size + n_evicted, cap),
         next_row=m.next_row,
+        max_depth=m.max_depth,
         n_rows=m.n_rows,
         max_probes=m.max_probes,
     )

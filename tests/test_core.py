@@ -184,8 +184,9 @@ class TestIDMap:
     def test_probe_and_claim_depths_count_useful_rounds(self):
         """Planted collisions: three ids homed on the last slot wrap to
         slots 0 and 1 (claimed in rounds 1, 2, 3), and an id homed on slot
-        0 then finds 0 and 1 taken (round 3). Both passes run all 32
-        rounds; the depths say how many did work."""
+        0 then finds 0 and 1 taken (round 3). Of the 32-round budget, the
+        claim runs until its last id is placed (its depth) and the probe
+        runs the table's ``max_depth`` as it was before the call."""
         cap = 64
         cand = jnp.arange(1, 4096, dtype=jnp.int64)
         home = np.asarray(idmap_lib._home(cand, cap))
@@ -193,18 +194,27 @@ class TestIDMap:
         first = np.asarray(cand)[home == 0][:1]
         m = idmap_lib.create(cap, 32)
 
+        def rounds(met):
+            return (int(met["idmap_probe_rounds"]), int(met["idmap_claim_rounds"]))
+
         m, _, _, met = idmap_lib.lookup_or_insert(m, jnp.asarray(last), jnp.int32(1))
         assert int(met["idmap_rounds"]) == 32
         assert (int(met["idmap_probe_depth"]), int(met["idmap_claim_depth"])) == (0, 3)
+        assert rounds(met) == (0, 3)
+        assert int(m.max_depth) == 3
         assert (int(met["idmap_lookups"]), int(met["idmap_hits"])) == (3, 0)
 
         m, _, _, met = idmap_lib.lookup_or_insert(m, jnp.asarray(first), jnp.int32(2))
         assert (int(met["idmap_probe_depth"]), int(met["idmap_claim_depth"])) == (0, 3)
+        assert rounds(met) == (3, 3)
+        assert int(met["idmap_rounds"]) == 32
 
         both = jnp.asarray(np.concatenate([last, first, [-1, -1]]), jnp.int64)
         m, _, new, met = idmap_lib.lookup_or_insert(m, both, jnp.int32(3))
         assert not bool(new.any())
         assert (int(met["idmap_probe_depth"]), int(met["idmap_claim_depth"])) == (3, 0)
+        assert rounds(met) == (3, 0)
+        assert int(met["idmap_rounds"]) == 32
         assert (int(met["idmap_lookups"]), int(met["idmap_hits"])) == (4, 4)
 
     @settings(max_examples=15, deadline=None)
@@ -225,6 +235,79 @@ class TestIDMap:
 # ---------------------------------------------------------------------------
 # exchange — single-device path (multi-device in test_multidevice.py)
 # ---------------------------------------------------------------------------
+
+def _small_engine(n_dev):
+    """One dim-4 group, 64 slots per shard: ~30 ids a shard collide."""
+    return EmbeddingEngine(
+        [FeatureSpec("f", transform="hash", emb_dim=4, pooling="sum")],
+        EngineConfig(mesh_axes=(), n_devices=n_dev, rows_per_shard=64,
+                     map_capacity_per_shard=64, u_budget=32, per_dest_cap=32,
+                     recv_budget=32))
+
+
+def _placed_depths(state):
+    """Per shard, the largest probe distance of a live key, from the table
+    itself (1-based; 0 for an empty shard)."""
+    m = state["dim4"]["idmap"]
+    keys, occ = np.asarray(m.keys), np.asarray(m.occupied)
+    cap = keys.shape[1]
+    out = []
+    for d in range(keys.shape[0]):
+        slots = np.flatnonzero(occ[d])
+        home = np.asarray(idmap_lib._home(jnp.asarray(keys[d][slots]), cap))
+        out.append(int(((slots - home) % cap + 1).max(initial=0)))
+    return out
+
+
+def _synthetic_rows(n_ids, seed=0):
+    r = np.random.default_rng(seed)
+    ids = r.choice(1 << 40, n_ids, replace=False).astype(np.int64)
+    emb = r.normal(size=(n_ids, 4)).astype(np.float32)
+    return {"dim4": {"ids": ids, "emb": emb,
+                     "slots": {"m": emb * 0.5, "v": emb * emb},
+                     "last_use": np.arange(n_ids, dtype=np.int32)}}
+
+
+class TestMaxDepth:
+    """``max_depth`` bounds the probe, so every path that saves or rebuilds
+    a table has to carry it: a bound too low would hide stored keys."""
+
+    @pytest.mark.parametrize("d_from,d_to", [(1, 1), (1, 2), (2, 1)])
+    def test_export_import_rebuilds_max_depth(self, d_from, d_to):
+        e1 = _small_engine(d_from)
+        st = e1.import_rows(_synthetic_rows(30 * d_from))
+        assert list(np.asarray(st["dim4"]["idmap"].max_depth)) == _placed_depths(st)
+        assert max(_placed_depths(st)) > 1  # the chains did collide
+
+        rows = e1.export_rows(st)
+        st2 = _small_engine(d_to).import_rows(rows)
+        depth = np.asarray(st2["dim4"]["idmap"].max_depth)
+        assert depth.tolist() == _placed_depths(st2)
+        back = _small_engine(d_to).export_rows(st2)
+        np.testing.assert_array_equal(np.sort(back["dim4"]["ids"]),
+                                      np.sort(rows["dim4"]["ids"]))
+
+    def test_max_depth_survives_save_restore(self, tmp_path):
+        from repro.checkpoint import saver
+
+        eng = _small_engine(2)
+        rows = _synthetic_rows(60)
+        st = eng.import_rows(rows)
+        depth = np.asarray(st["dim4"]["idmap"].max_depth)
+        assert depth.max() > 1
+        saver.save(st, tmp_path, step=1, n_shards=2)
+        out = saver.restore(tmp_path, eng.init_state())
+        np.testing.assert_array_equal(np.asarray(out["dim4"]["idmap"].max_depth), depth)
+        # every key is still found by a probe bounded by the restored depth
+        ids = rows["dim4"]["ids"]
+        back = eng.export_rows(out)
+        np.testing.assert_array_equal(np.sort(back["dim4"]["ids"]), np.sort(ids))
+        for d in range(2):
+            m = jax.tree.map(lambda x: jnp.asarray(x[d]), out["dim4"]["idmap"])
+            keys = np.asarray(m.keys)[np.asarray(m.occupied)]
+            assert bool((np.asarray(idmap_lib.lookup(m, jnp.asarray(keys)))
+                         != idmap_lib.OVERFLOW_ROW).all())
+
 
 def _spec(u=32, c=64, r=64):
     return exchange.ExchangeSpec(axes=(), n_devices=1, u_budget=u,

@@ -109,24 +109,42 @@ class TestExchangeMultiDevice:
 
             def step(sp_state, vals, splits):
                 st = jax.tree.map(lambda x: x[0], sp_state)
-                _, _, _, met = eng.fetch_local(st, {"f": Ragged(vals, splits)},
-                                               jnp.int32(1))
-                return ({k: v[None] for k, v in met.items()},
+                st, _, _, met = eng.fetch_local(st, {"f": Ragged(vals, splits)},
+                                                jnp.int32(1))
+                return (jax.tree.map(lambda x: x[None], st),
+                        {k: v[None] for k, v in met.items()},
                         reduce_metrics(met, ("data",)))
 
-            per, red = jax.jit(shard_map(
-                step, mesh=mesh, in_specs=(sp, sp, sp), out_specs=(sp, P()),
-                check_vma=False))(eng.init_state(), vals, splits)
-            assert set(red) == set(per)
-            for k, v in red.items():
-                each = np.asarray(per[k])
-                want = each.max() if k.split("/")[-1] in MAX_METRICS else each.sum()
-                assert int(v) == int(want), (k, v, each)
+            fetch = jax.jit(shard_map(
+                step, mesh=mesh, in_specs=(sp, sp, sp), out_specs=(sp, sp, P()),
+                check_vma=False))
+            state, per, red = fetch(eng.init_state(), vals, splits)
+            # a second call: its probe runs each chip's max_depth
+            _, per2, red2 = fetch(state, vals + (1 << 41), splits)
+            for p_, r_ in ((per, red), (per2, red2)):
+                assert set(r_) == set(p_)
+                for k, v in r_.items():
+                    each = np.asarray(p_[k])
+                    want = each.max() if k.split("/")[-1] in MAX_METRICS else each.sum()
+                    assert int(v) == int(want), (k, v, each)
+                assert int(r_["dim4/idmap_rounds"]) == 32
+                assert int(r_["dim4/exch_ids"]) == 4 * 48
+                # the claim runs to its depth on each chip, or to the budget
+                # where an id found no slot; the max leaves
+                claim = np.asarray(p_["dim4/idmap_claim_rounds"])
+                np.testing.assert_array_equal(claim, np.where(
+                    np.asarray(p_["dim4/idmap_probe_overflow"]) > 0, 32,
+                    np.asarray(p_["dim4/idmap_claim_depth"])))
+                assert int(r_["dim4/idmap_claim_rounds"]) == claim.max()
+            assert int(red["dim4/idmap_inserted"]) == int(red["dim4/exch_recv_uniq"])
             depth = np.asarray(per["dim4/idmap_claim_depth"])
             assert depth.max() > 1 and depth.sum() > depth.max()
-            assert int(red["dim4/idmap_rounds"]) == 32
-            assert int(red["dim4/exch_ids"]) == 4 * 48
-            assert int(red["dim4/idmap_inserted"]) == int(red["dim4/exch_recv_uniq"])
+            # the probe runs each chip's max_depth: 0, then the first depths
+            assert int(red["dim4/idmap_probe_rounds"]) == 0
+            probe = np.asarray(per2["dim4/idmap_probe_rounds"])
+            np.testing.assert_array_equal(probe, depth)
+            np.testing.assert_array_equal(np.asarray(state["dim4"]["idmap"].max_depth), depth)
+            assert int(red2["dim4/idmap_probe_rounds"]) == probe.max() < probe.sum()
             print("COUNTERS_OK", depth.tolist())
         """, n_dev=4)
         assert "COUNTERS_OK" in out
