@@ -60,9 +60,9 @@ def readings(h, seed: int, trainer=None, which=None) -> dict:
     for v in variants(h) if which is None else which:
         t = time.perf_counter()
         if v == "control":
-            out[v] = h.reference(seed, ring, quantize=h.ref.common.fp8)
+            out[v] = h.reference(seed, ring, quantize=h.common.fp8)
         elif v == "bf16":
-            out[v] = h.reference(seed, ring, quantize=h.ref.common.bf16)
+            out[v] = h.reference(seed, ring, quantize=h.common.bf16)
         else:
             out[v] = h.reference(seed, ring, fault=v)
         # the reference put in the program's place

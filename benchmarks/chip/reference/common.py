@@ -5,13 +5,16 @@ published contract of a step: how a raw id becomes a table key (the feature
 hash and the table salt), how a new row is initialised from its key, the
 loss, and the two optimizers. Each is written out here from that contract:
 
-* a raw id of column ``c`` becomes ``splitmix64(raw ^ colsalt(c))`` and then
+* a raw id of column ``c`` becomes ``splitmix64(raw ^ colsalt(c))`` (a
+  token column takes ``|raw| mod vocab`` instead) and then
   ``splitmix64(that ^ (splitmix64(tablesalt(c)) + C1))``, where ``colsalt``
   mixes the 31-bit FNV-1a of the table's name and ``tablesalt`` is its 63-bit
   FNV-1a;
 * a new row of width ``d`` is ``(2 u - 1) / sqrt(d)``, ``u`` the top 24 bits
   of ``splitmix64(key * C1 + column)``; its Adam moments start at 0;
-* the loss is the mean sigmoid cross-entropy over the batch;
+* the loss is the mean over the batch of each example's loss, which the
+  configuration's module gives (``example_losses``: a recommendation
+  model's sigmoid cross-entropy, ``sigmoid_ce``);
 * the towers' parameters take AdamW with the gradient clipped to a global
   norm (decoupled weight decay), the rows take lazy row-wise Adam: only rows
   of the batch move, with bias correction by the global step.
@@ -31,6 +34,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+jax.config.update("jax_enable_x64", True)  # table keys are 64-bit integers
 
 C1 = 0x9E3779B97F4A7C15
 C2 = 0xBF58476D1CE4E5B9
@@ -60,10 +65,14 @@ def _fnv1a64(name: str) -> int:
     return h
 
 
-def table_keys(raw: np.ndarray, table: str) -> np.ndarray:
-    """int64 table keys of raw ids of one column that reads ``table``."""
-    col_salt = _splitmix64_int(_fnv1a64(table) & 0x7FFFFFFF)
-    hashed = splitmix64_np(raw.astype(np.int64).view(np.uint64) ^ np.uint64(col_salt))
+def table_keys(raw: np.ndarray, table: str, vocab: int | None = None) -> np.ndarray:
+    """int64 table keys of raw ids of one column that reads ``table``: a
+    hashed column, or with ``vocab`` a token column (ids modulo ``vocab``)."""
+    if vocab is None:
+        col_salt = _splitmix64_int(_fnv1a64(table) & 0x7FFFFFFF)
+        hashed = splitmix64_np(raw.astype(np.int64).view(np.uint64) ^ np.uint64(col_salt))
+    else:
+        hashed = (np.abs(raw.astype(np.int64)) % vocab).view(np.uint64)
     tab_salt = _fnv1a64(table) & 0x7FFFFFFFFFFFFFFF
     mix = np.uint64((_splitmix64_int(tab_salt) + C1) & M64)
     return splitmix64_np(hashed ^ mix).view(np.int64)
@@ -175,9 +184,10 @@ def mlp(p: dict, x: jax.Array, quantize, final_relu: bool = False) -> jax.Array:
     return x
 
 
-def bce(logits: jax.Array, labels: jax.Array) -> jax.Array:
+def sigmoid_ce(logits: jax.Array, labels: jax.Array) -> jax.Array:
+    """Each example's sigmoid cross-entropy of its logit against its label."""
     z, y = logits.astype(jnp.float32), labels.astype(jnp.float32)
-    return jnp.mean(jnp.maximum(z, 0.0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z))))
+    return jnp.maximum(z, 0.0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
 
 
 def flat(tree: dict, prefix: str = "") -> dict:
@@ -194,20 +204,27 @@ def flat(tree: dict, prefix: str = "") -> dict:
 
 # ------------------------------------------------------------------ training
 def _column_keys(model, cfg, batch: dict, chips: int, fault: str | None):
-    """Per group: (B, F) int64 keys, and (B, F) int64 row-identity keys.
+    """Per group: (B, K) int64 keys, and (B, K) int64 row-identity keys.
 
-    Row identity is the key, except under the ``no_exchange`` fault, where
-    each chip keeps its own copy of a row (the key paired with the chip of
-    the example). The ``altered_id`` fault flips the low bit of the first
-    column's keys, as a feature hash that altered its output would."""
+    ``model.groups(cfg)`` lists each group's columns as ``(name, table)``,
+    or ``(name, table, vocab)`` for a token column. A column holds the same
+    number of ids in every row (a categorical feature one, a sequence of
+    tokens T), over ``chips`` shards of rows laid end to end; a row's K keys
+    are its ids of each column in turn. Row identity is the key, except
+    under the ``no_exchange`` fault, where each chip keeps its own copy of a
+    row (the key paired with the chip of the example). The ``altered_id``
+    fault flips the low bit of the first column's keys, as a feature hash
+    that altered its output would."""
     out = {}
     for gi, (group, dim, columns) in enumerate(model.groups(cfg)):
         cols = []
-        for name, table in columns:
-            cols.append(table_keys(batch[name][0], table))
+        for name, table, *vocab in columns:
+            values, splits = batch[name]
+            rows = len(splits) - chips
+            cols.append(table_keys(values, table, *vocab).reshape(rows, -1))
         if fault == "altered_id" and gi == 0:
             cols[0] = cols[0] ^ 1
-        keys = np.stack(cols, axis=1)
+        keys = np.concatenate(cols, axis=1)
         ident = keys
         if fault == "no_exchange":
             b = keys.shape[0]
@@ -221,8 +238,8 @@ def run(model, cfg: dict, seed: int, batches: list, chips: int,
         quantize=identity, fault: str | None = None) -> dict:
     """Train ``len(batches)`` steps from the seed; return the readings.
 
-    ``batches`` are host batches ({column: (values, row_splits)}), one id per
-    row of every id column. Returns the loss of every step, each leaf's
+    ``batches`` are host batches ({column: (values, row_splits)}), as many
+    ids in every row of an id column. Returns the loss of every step, each leaf's
     gradient norm as the optimizer got it at step 1, and each leaf's change
     after the last step; leaves are ``"dense/<path>"`` and
     ``"sparse/<group>"``; the first moments after step 1 (``m1``: the
@@ -304,12 +321,12 @@ def run(model, cfg: dict, seed: int, batches: list, chips: int,
 
 
 def _loss(model, cfg, params, rows, batch, quantize, fault):
-    logits = model.forward(params, cfg, rows, batch, quantize)
-    labels = batch["label"].reshape(-1)
+    """The mean of the model's per-example losses; under the ``half_batch``
+    fault, of the first half of the batch."""
+    losses = model.example_losses(params, cfg, rows, batch, quantize)
     if fault == "half_batch":
-        half = logits.shape[0] // 2
-        return bce(logits[:half], labels[:half])
-    return bce(logits, labels)
+        losses = losses[:losses.shape[0] // 2]
+    return jnp.mean(losses)
 
 
 def _adamw(hp, params, grads, m, v, t):

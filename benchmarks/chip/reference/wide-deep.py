@@ -62,6 +62,11 @@ def forward(params: dict, cfg: dict, rows: dict, batch: dict, quantize) -> jax.A
     return quantize(deep_logit + wide_logit) + params["bias"]
 
 
+def example_losses(params: dict, cfg: dict, rows: dict, batch: dict, quantize) -> jax.Array:
+    """(B,) sigmoid cross-entropy of each example's logit against its label."""
+    return common.sigmoid_ce(forward(params, cfg, rows, batch, quantize), batch["label"].reshape(-1))
+
+
 def forward_flops_per_example(cfg: dict) -> int:
     """Multiply-adds of one example's forward pass, counted twice."""
     m = cfg["model"]

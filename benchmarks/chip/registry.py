@@ -5,6 +5,8 @@
   traffic/<mix>.json         a traffic mix, read by ``traffic.py``
   metrics/<metric>.py        one reader per metric: ``read(ctx)``
   limits/<workload>.json     the limits of the comparison, per cell
+  families/<family>.py       how the harness drives one family of the
+                             program's cells (``ArchConfig.family``)
 
 A new cell, configuration, mix or metric is new files and a new entry; no
 file that is there changes.
@@ -45,10 +47,17 @@ class Bench:
         return json.loads(p.read_text()) if p.exists() else None
 
     def reference(self, config: str):
+        """The configuration's plain reference; None where it has none yet
+        (a cell needs one, a CPU test of the harness does not)."""
+        if not (self.dir / "reference" / f"{config}.py").exists():
+            return None
         return load(self.dir / "reference", config)
 
     def metric(self, name: str):
         return load(self.dir / "metrics", name)
+
+    def family(self, name: str):
+        return load(self.dir / "families", name)
 
     def metrics_of(self, workload: str, trace: bool) -> list[dict]:
         """The cell's metrics: end-to-end ones without a trace, per-layer

@@ -13,7 +13,9 @@ traffic mix, and trains the mix's first ``check_steps`` batches through
 batch on the device through the cell's batch shardings, and the clock stops
 once the returned state is ready. With ``--trace 1`` the window runs under
 the profiler, for at most ``TRACE_STEPS`` steps, and the per-layer metrics
-are read from the trace.
+are read from the trace (by op kind and by the program's stage names), the
+window's step counters and the engine's exchange budgets: ``context`` lists
+what a metric's ``read(ctx)`` sees.
 
 After the window the program's state is freed and the plain reference
 replays the first steps; ``check.py`` compares the two. The last lines of
@@ -40,6 +42,10 @@ BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parents[1]
 TRACE_STEPS = 8
 NO_CHIP = 3
+sys.path.insert(0, str(BENCH))
+
+import harness  # noqa: E402
+import registry  # noqa: E402
 
 
 def parser() -> argparse.ArgumentParser:
@@ -53,16 +59,76 @@ def parser() -> argparse.ArgumentParser:
     return p
 
 
+def window(h, trainer, state, ring, seconds: float, max_steps: int | None = None,
+           trace_dir: pathlib.Path | None = None, keep_trace: str | None = None):
+    """Drive the Trainer on the ring from the first batch after the checked
+    ones for ``seconds`` (or ``max_steps``); under the profiler where
+    ``trace_dir`` is given, then reduced to its summary."""
+    import jax
+
+    trace_lib = registry.load(BENCH, "trace")  # not the standard library's trace
+    if trace_dir is not None:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(str(trace_dir))
+    pulls: list[float] = []
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(trace_lib.WINDOW):
+        res = trainer.run(state, h.batches(ring, h.n_check, deadline=t0 + seconds,
+                                           max_steps=max_steps, pulls=pulls),
+                          start_step=h.n_check)
+        jax.block_until_ready(res.state)
+    t1 = time.perf_counter()
+    summary = None
+    if trace_dir is not None:
+        jax.profiler.stop_trace()
+        xplane = trace_lib.find_xplane(trace_dir)
+        if keep_trace and xplane is not None:
+            shutil.copytree(xplane.parent, keep_trace, dirs_exist_ok=True)
+        if xplane is not None:
+            hlo = trainer.compiled().as_text()
+            stages = (trace_lib.module_name(hlo), trace_lib.stage_map(hlo))
+            summary = trace_lib.reduce(xplane, trace_lib.fusion_roots(hlo), stages=stages)
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return types.SimpleNamespace(res=res, pulls=pulls, t0=t0, t1=t1, summary=summary)
+
+
+def context(h, w, ring, setup_s: float, peak: int | None, device_kind: str):
+    """What a metric's ``read(ctx)`` sees of one run."""
+    import numpy as np
+
+    import traffic
+
+    steps = w.res.steps_run
+    return types.SimpleNamespace(
+        workload=h.workload, chips=h.chips, global_batch=h.batch, cfg=h.cfg,
+        device_kind=device_kind, setup_s=setup_s, peak_bytes=peak,
+        steps=steps, window_s=w.t1 - w.t0, intervals=list(np.diff(w.pulls)),
+        examples=steps * h.batch, trace=w.summary, history=w.res.metrics_history,
+        budgets=h.budgets,
+        forward_flops_per_example=h.ref.forward_flops_per_example(h.cfg) if h.ref else None,
+        unique_per_step=[traffic.unique_per_group(ring[(h.n_check + i) % len(ring)], h.groups)
+                         for i in range(steps)],
+        row_bytes={g: 4 * d for g, d in h.dims.items()},
+        ids_per_step=sum(c["values"] for c in h.columns if c["ids"]),
+    )
+
+
+def read_metrics(h, ctx, trace: bool) -> dict:
+    """{metric: {"value", "unit"}} of the cell's metrics (its per-layer ones
+    with a trace), leaving out each that finds nothing to read."""
+    out = {}
+    for m in h.bench.metrics_of(h.workload, trace):
+        value = h.bench.metric(m["name"]).read(ctx)
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+    return out
+
+
 def run(argv=None, root: pathlib.Path = ROOT, require_tpu: bool = True) -> dict | None:
     """One run; returns the result (None without the chip it needs)."""
     args = parser().parse_args(argv)
-    sys.path.insert(0, str(BENCH))
-    import harness
-
     harness.set_env(root)
     import jax
-
-    import registry
 
     chips = int(registry.Bench(root).workload(args.workload)["chips"])
     devices = jax.devices()
@@ -71,11 +137,7 @@ def run(argv=None, root: pathlib.Path = ROOT, require_tpu: bool = True) -> dict 
               f"{devices[0].platform} device(s)", file=sys.stderr)
         return None
     harness.use_cache()
-    import numpy as np
-
     import check
-    import traffic
-    trace_lib = registry.load(BENCH, "trace")  # not the standard library's trace
 
     h = harness.Harness(root, args.workload, devices)
     ring = h.ring(args.seed)
@@ -84,62 +146,30 @@ def run(argv=None, root: pathlib.Path = ROOT, require_tpu: bool = True) -> dict 
     state, prog = h.checked_steps(trainer, state, dense0, ring)
     jax.block_until_ready(state)
 
-    # ----------------------------------------------------------- the window
-    trace_dir = BENCH / ".traces" / f"{args.workload}-{args.seed}"
-    if args.trace:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        jax.profiler.start_trace(str(trace_dir))
-    pulls: list[float] = []
-    t0 = time.perf_counter()
-    setup_s = t0 - T_START
-    with jax.profiler.TraceAnnotation(trace_lib.WINDOW):
-        res = trainer.run(state, h.batches(ring, h.n_check, deadline=t0 + args.seconds,
-                                           max_steps=TRACE_STEPS if args.trace else None,
-                                           pulls=pulls), start_step=h.n_check)
-        jax.block_until_ready(res.state)
-    t1 = time.perf_counter()
-    summary = None
-    if args.trace:
-        jax.profiler.stop_trace()
-        xplane = trace_lib.find_xplane(trace_dir)
-        if args.keep_trace and xplane is not None:
-            shutil.copytree(xplane.parent, args.keep_trace, dirs_exist_ok=True)
-        roots = trace_lib.fusion_roots(trainer.compiled().as_text())
-        summary = trace_lib.reduce(xplane, roots) if xplane is not None else None
-        shutil.rmtree(trace_dir, ignore_errors=True)
-    steps, hist = res.steps_run, res.metrics_history
+    trace_dir = BENCH / ".traces" / f"{args.workload}-{args.seed}" if args.trace else None
+    w = window(h, trainer, state, ring, args.seconds,
+               max_steps=TRACE_STEPS if args.trace else None,
+               trace_dir=trace_dir, keep_trace=args.keep_trace)
+    setup_s = w.t0 - T_START
+    hist = w.res.metrics_history
     peak = harness.peak_bytes(h.devices)
 
     # --------------------------------------- free the program, then check
-    h.free(res.state)
-    del res, state, trainer
+    h.free(w.res.state)
+    del state, trainer
     ref = h.reference(args.seed, ring)
     nums = h.numbers(prog, ref)
     correct, compared = check.judge(nums, h.bench.limits(args.workload))
 
     # -------------------------------------------------------------- metrics
-    window_uniq = [traffic.unique_per_group(ring[(h.n_check + i) % len(ring)], h.groups)
-                   for i in range(steps)]
-    inserted = sum(x.get(f"{g}/idmap_inserted", 0.0) for x in hist for g in h.groups)
     kind = devices[0].device_kind
-    ctx = types.SimpleNamespace(
-        workload=args.workload, chips=chips, global_batch=h.batch, cfg=h.cfg,
-        device_kind=kind, setup_s=setup_s, peak_bytes=peak,
-        steps=steps, window_s=t1 - t0, intervals=list(np.diff(pulls)),
-        examples=steps * h.batch, trace=summary,
-        forward_flops_per_example=h.ref.forward_flops_per_example(h.cfg),
-        unique_per_step=window_uniq, row_bytes={g: 4 * d for g, d in h.dims.items()},
-        ids_per_step=sum(c["values"] for c in h.columns if c["ids"]),
-    )
-    metrics = {}
-    for m in h.bench.metrics_of(args.workload, bool(args.trace)):
-        value = h.bench.metric(m["name"]).read(ctx)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    ctx = context(h, w, ring, setup_s, peak, kind)
+    inserted = sum(x.get(f"{g}/idmap_inserted", 0.0) for x in hist for g in h.groups)
+    summary = w.summary
     device = {"platform": devices[0].platform, "kind": kind, "count": chips,
               "memory_peak_bytes": peak}
-    out = {"correct": correct, "attempted": steps, "failed": 0, "metrics": metrics,
-           "device": device}
+    out = {"correct": correct, "attempted": ctx.steps, "failed": 0,
+           "metrics": read_metrics(h, ctx, bool(args.trace)), "device": device}
     if summary is not None:
         device.update(busy_s=summary["busy_s"], window_s=summary["window_s"])
         out["breakdown"] = {"device_ops": summary["device_ops"],
@@ -147,8 +177,9 @@ def run(argv=None, root: pathlib.Path = ROOT, require_tpu: bool = True) -> dict 
     out["window"] = {
         "compiles": sum(x.get("compiles", 0) for x in hist),
         "peak_bytes_step1": prog["peak_bytes_step1"],
-        "new_id_share": inserted / max(1, sum(sum(u.values()) for u in window_uniq)),
+        "new_id_share": inserted / max(1, sum(sum(u.values()) for u in ctx.unique_per_step)),
         "op_kind_s": summary and summary["kind_s"],
+        "stage_s": summary and summary["stage_s"],
         "worst": {k: v[1] for k, v in nums.items()},
         "readings": {k: {n: r[n] for n in ("losses", "grad_norms", "change_norms")}
                      for k, r in (("program", prog), ("reference", ref))},

@@ -1,7 +1,10 @@
 """CPU tests of the plain references against the program at smoke size:
-the same keys, the same initial rows, and, over the checked steps, the same
-losses, rows with their Adam moments, and tower parameters to within what
-the program's bfloat16 tower products allow."""
+the same keys (of hashed columns and of token columns with many ids to a
+row), the same initial rows, and, over the checked steps, the same losses,
+rows with their Adam moments, and tower parameters to within what the
+program's bfloat16 tower products allow; and the references' own readings
+at one seed, which are recorded to the bit."""
+import json
 import pathlib
 import sys
 
@@ -11,12 +14,16 @@ import numpy as np
 import pytest
 
 BENCH = pathlib.Path(__file__).resolve().parent
+ROOT = BENCH.parents[1]
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH / "reference"))
 
 import check  # noqa: E402
 import common  # noqa: E402
+import conftest  # noqa: E402
 import harness  # noqa: E402
+import registry  # noqa: E402
+import traffic  # noqa: E402
 
 # share of a state's change or size by which the program may differ from the
 # float32 reference: the towers' products round to bfloat16 (2^-8)
@@ -44,6 +51,74 @@ def test_keys_and_initial_rows_match_the_program():
         want = blocks._hash_uniform(jnp.asarray(ref), dim) * np.float32(1 / np.sqrt(dim))
         np.testing.assert_array_equal(np.asarray(common.init_rows(jnp.asarray(ref), dim)),
                                       np.asarray(want))
+
+
+def test_token_column_keys_take_many_ids_to_a_row():
+    """A (B·T)-long token column, T ids to a row over two chips' shards,
+    gives (B, T) keys, row by row, equal to the program's engine ids."""
+    from repro.core.embedding_engine import EmbeddingEngine, EngineConfig
+    from repro.core.feature_engine import FeatureEngine, FeatureSpec
+    from repro.io.ragged import Ragged
+
+    vocab, b, t, chips = 1000, 6, 5, 2
+    spec = FeatureSpec("tokens", transform="mod", vocab_size=vocab, emb_dim=8,
+                       pooling="values")
+    raw = np.random.default_rng(1).integers(0, 3 * vocab, size=b * t, dtype=np.int64)
+    splits = np.tile(np.arange(b // chips + 1, dtype=np.int32) * t, chips)
+    ids, _ = FeatureEngine([spec]).apply(
+        {"tokens": Ragged(jnp.asarray(raw), jnp.arange(b * t + 1, dtype=jnp.int32))})
+    prog = np.asarray(EmbeddingEngine([spec], EngineConfig(
+        ("data",), 1, u_budget=64, per_dest_cap=64, recv_budget=64)).engine_ids(ids)["dim8"])
+
+    class Tokens:
+        @staticmethod
+        def groups(cfg):
+            return [("dim8", 8, [("tokens", "tokens", vocab)])]
+
+    for fault in (None, "no_exchange"):
+        (dim, keys, ident), = common._column_keys(Tokens, {}, {"tokens": (raw, splits)},
+                                                  chips, fault).values()
+        assert dim == 8 and keys.shape == (b, t)
+        np.testing.assert_array_equal(keys.reshape(-1), prog)
+    # under no_exchange, a row's ids are owned by the chip of its example
+    np.testing.assert_array_equal(ident[..., 0], np.repeat([[0], [0], [0], [1], [1], [1]], t, 1))
+
+
+def _smoke_columns(model, cfg, chips, batch=256):
+    cols = [{"name": n, "values": batch, "splits": batch + chips, "ids": True, "table": t}
+            for _, _, group in model.groups(cfg) for n, t in group]
+    return cols + [{"name": n, "values": batch * k, "splits": batch + chips, "ids": False,
+                    "table": None} for n, k in (("label", 1), ("dense", 13))]
+
+
+READINGS = json.loads((BENCH / "testdata" / "reference_readings.json").read_text())
+
+
+@pytest.mark.parametrize("case", sorted(READINGS))
+def test_reference_readings_are_recorded_to_the_bit(case):
+    """The references' losses, step-1 gradient norms and 3-step changes at
+    the smoke widths, seed 2**31 + 7, batch 256, on the CPU, equal those
+    that ``testdata/reference_readings.json`` records: read with the loss
+    taken as a mean sigmoid cross-entropy in ``common`` itself, before each
+    configuration's module gave its per-example losses. A fault's run
+    (``<config>/<fault>``) is read on two chips for ``no_exchange``, one
+    otherwise."""
+    config, fault = case.split("/")
+    fault = None if fault == "none" else fault
+    chips = 2 if fault == "no_exchange" else 1
+    bench = registry.Bench(ROOT)
+    cfg = dict(bench.config("dlrm-mlperf"), arch=config, model=conftest.SMOKE_MODELS[config],
+               rows_per_table_per_chip=512, global_batch=256)
+    model = registry.load(BENCH / "reference", config)
+    mix = dict(bench.traffic("train.zipf"), ring_batches=3)
+    seed = 2**31 + 7
+    ring = traffic.make_ring(mix, cfg, chips, _smoke_columns(model, cfg, chips), seed)
+    host = [{k: v for k, v in b.items() if not k.startswith("_")} for b in ring]
+    r = common.run(model, cfg, seed, host, chips, fault=fault)
+    got = {"losses": [float(x).hex() for x in r["losses"]],
+           **{k: {leaf: float(v).hex() for leaf, v in sorted(r[k].items())}
+              for k in ("grad_norms", "change_norms")}}
+    assert got == READINGS[case]
 
 
 def test_bf16_witness_rounds_as_bfloat16_does():

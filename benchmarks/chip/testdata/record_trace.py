@@ -1,8 +1,11 @@
 """Records the trace that the reduction's test reads: three steps of a
 small jitted program with a sort, a scatter-add and a matmul, under the
-harness's window and phase annotations, on one TPU chip. It writes
-``small.xplane.pb`` and ``small.hlo.txt`` (the program's optimized HLO, which
-names what each fusion is rooted in) into OUT (default: beside this file).
+harness's window and phase annotations, on one TPU chip. The sort runs in
+the program's stage ``recis.exchange.bucket``; the scatter-add is the
+backward of a gather in ``recis.embed.route``; the matmul is in no stage.
+It writes ``small.xplane.pb`` and ``small.hlo.txt`` (the program's
+optimized HLO, which names what each fusion is rooted in and each
+instruction's stage) into OUT (default: beside this file).
 
     python3 benchmarks/chip/testdata/record_trace.py [OUT]
 """
@@ -22,16 +25,24 @@ def main() -> None:
     import jax.numpy as jnp
 
     sys.path.insert(0, str(HERE.parent))
+    sys.path.insert(0, str(HERE.parents[2] / "src"))
     import registry
+
+    from repro.obs.stages import stage
 
     trace = registry.load(HERE.parent, "trace")
     if jax.devices()[0].platform != "tpu":
         raise SystemExit("records a TPU trace")
 
+    def route(t, idx, x):
+        with stage("recis.embed.route"):
+            return jnp.sum(t[idx] * x)
+
     @jax.jit
     def step(x, idx):
-        s = jnp.sort(x[:, 0])
-        t = jnp.zeros((4096, 128), jnp.float32).at[idx].add(x)
+        with stage("recis.exchange.bucket"):
+            s = jnp.sort(x[:, 0])
+        t = jax.grad(route)(jnp.zeros((4096, 128), jnp.float32), idx, x)  # a scatter-add
         return s, t, x @ x.T
 
     x = jax.random.normal(jax.random.PRNGKey(0), (8192, 128))

@@ -12,6 +12,15 @@ around the traced steps. Inside it, per device plane (``/device:TPU:<n>``):
   kind is its opcode, and for a fusion the opcode its computation is rooted
   in, read from the program's optimized HLO (``fusion_roots``): XLA on the
   TPU puts most scatters and gathers in such fusions;
+* time by stage: the same own times, summed by the stage and direction
+  (``forward`` or ``backward``) of each op of the traced step's runs, which
+  the caller reads from that step's optimized HLO (``stages``: the module's
+  name and ``stage_map`` of its text, which reads the program's
+  ``recis.<layer>.<stage>`` scopes from each op's metadata by the rules
+  here, not the program's). Where the trace has a line of
+  module runs (``XLA Modules``), only ops inside a run of the step's module
+  are looked up. Time that no stage names goes to the stage ``""``, so the
+  stages add up to the busy time;
 * idle gaps, on the first device plane: the complement of its busy union in
   the window, each named by the Trainer phase (host annotation ``repro/<phase>``) that overlaps it
   most, or ``host`` where none does.
@@ -21,6 +30,7 @@ model: later metrics read the summary this returns.
 """
 from __future__ import annotations
 
+import bisect
 import collections
 import pathlib
 import re
@@ -28,6 +38,7 @@ import re
 WINDOW = "bench/window"
 PHASE_PREFIX = "repro/"
 OP_LINES = ("XLA Ops",)
+MODULE_LINES = ("XLA Modules",)
 
 KINDS = ("sort", "scatter", "gather", "all-to-all")
 _PASS_THROUGH = ("bitcast", "convert", "copy", "reshape", "transpose", "get-tuple-element")
@@ -96,6 +107,87 @@ def fusion_roots(hlo_text: str) -> dict[str, str]:
     return roots
 
 
+_STAGE_RE = re.compile(r"recis(?:\.[A-Za-z0-9_]+)+")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def stage_of(op_name: str) -> str:
+    """The innermost ``recis.`` segment of an HLO ``op_name`` (the program
+    names a stage by such a ``jax.named_scope``), or ``""``."""
+    found = _STAGE_RE.findall(op_name)
+    return found[-1] if found else ""
+
+
+def backward_of(op_name: str) -> bool:
+    """True for an op that ``jax.grad`` made: its path is transposed."""
+    return "transpose(" in op_name
+
+
+def op_names(hlo_text: str) -> dict[str, str]:
+    """{instruction: op_name} of every instruction of an optimized HLO
+    module's text, as the stages are read from it.
+
+    The compiler makes some instructions anew and without metadata (it
+    rewrites a scatter on two index arrays into one on a linear index, say).
+    Such an instruction, where it has no stage of its own, takes the op_name
+    of its fusion: for a fusion, of the computation it calls; inside a fused
+    computation, of that computation. A fused computation's op_name is that
+    of its last instruction with a stage (the root is printed last).
+    """
+    comps: dict[str, list[tuple[str, str, str | None]]] = {}
+    fused: set[str] = set()
+    body = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%(\S+) .*\{\s*$", line)
+        if head and " = " not in line:
+            body = comps.setdefault(head.group(1), [])
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?%([^\s=]+) = ", line)
+        if body is None or m is None:
+            continue
+        own = _OP_NAME.search(line)
+        calls = re.search(r"calls=%([\w.\-]+)", line) if " fusion(" in line else None
+        if calls:
+            fused.add(calls.group(1))
+        body.append((m.group(1), own.group(1) if own else "",
+                     calls.group(1) if calls else None))
+
+    comp_name: dict[str, str] = {}
+
+    def of_comp(comp: str) -> str:
+        if comp not in comp_name:
+            found = ""
+            for _, own, calls in reversed(comps.get(comp, [])):
+                found = own if stage_of(own) else of_comp(calls) if calls else ""
+                if found:
+                    break
+            comp_name[comp] = found
+        return comp_name[comp]
+
+    out = {}
+    for comp, body in comps.items():
+        for name, own, calls in body:
+            if not stage_of(own):
+                own = (calls and of_comp(calls)) or (comp in fused and of_comp(comp)) or own
+            out[name] = own
+    return out
+
+
+def stage_map(hlo_text: str) -> dict[str, tuple[str, str]]:
+    """{instruction: (stage, direction)} of an optimized HLO module: the
+    stage ``""`` where none is named, the direction ``forward`` or
+    ``backward``. ``reduce`` takes it as its ``stages``."""
+    return {name: (stage_of(op), "backward" if backward_of(op) else "forward")
+            for name, op in op_names(hlo_text).items()}
+
+
+def module_name(hlo_text: str) -> str:
+    """The name of an optimized HLO module (``HloModule <name>, ...``), as
+    the trace's module runs name it before their fingerprint."""
+    m = re.match(r"\s*HloModule\s+([^\s,]+)", hlo_text)
+    return m.group(1) if m else ""
+
+
 def op_kind(text: str, roots: dict[str, str] | None = None) -> str:
     """``sort``, ``scatter``, ``gather``, ``all-to-all`` or ``other``: the
     op's opcode, or for a fusion the opcode it is rooted in."""
@@ -150,22 +242,42 @@ def _overlap(a0, a1, b0, b1):
     return max(0, min(a1, b1) - max(a0, b0))
 
 
-def reduce(path, roots: dict[str, str] | None = None, top: int = 10) -> dict | None:
+def _in_runs(ops, runs):
+    """For each (start, end, text) op: whether it lies in one of ``runs``
+    ((start, end) intervals), or every op where there are none."""
+    if runs is None:
+        return [True] * len(ops)
+    starts = [r[0] for r in runs]
+    out = []
+    for s, e, _ in ops:
+        i = bisect.bisect_right(starts, s) - 1
+        out.append(i >= 0 and s < runs[i][1])
+    return out
+
+
+def reduce(path, roots: dict[str, str] | None = None, top: int = 10,
+           stages: tuple[str, dict[str, tuple[str, str]]] | None = None) -> dict | None:
     """Summary of the trace at ``path``; None when it holds no window or no
-    device op in it. ``roots``: ``fusion_roots`` of the traced program."""
+    device op in it. ``roots``: ``fusion_roots`` of the traced program;
+    ``stages``: its module's name and each instruction's (stage,
+    direction)."""
     from jax.profiler import ProfileData
 
+    module, by_instr = stages or ("", {})
     pd = ProfileData.from_file(str(path))
     window, phases, devices = None, [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
-            ops = []
+            ops, runs = [], None
             for line in plane.lines:
                 if line.name in OP_LINES:
                     ops.extend((e.start_ns, e.start_ns + e.duration_ns, e.name)
                                for e in line.events)
+                elif line.name in MODULE_LINES:
+                    runs = sorted((e.start_ns, e.start_ns + e.duration_ns) for e in line.events
+                                  if e.name.split("(", 1)[0] == module)
             if ops:
-                devices.append(ops)
+                devices.append((ops, runs))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
@@ -181,12 +293,19 @@ def reduce(path, roots: dict[str, str] | None = None, top: int = 10) -> dict | N
     busy = 0.0
     kinds: dict[str, float] = collections.defaultdict(float)
     by_op: dict[str, float] = collections.defaultdict(float)
+    by_stage: dict[str, dict[str, float]] = collections.defaultdict(
+        lambda: {"forward": 0.0, "backward": 0.0})
     gaps = []
-    for i, ops in enumerate(devices):
-        inside = [(max(s, w0), min(e, w1), name) for s, e, name in ops if e > w0 and s < w1]
-        for (_, _, name), own in zip(inside, _exclusive(inside)):
+    for i, (ops, runs) in enumerate(devices):
+        inside = [(s, e, name) for s, e, name in ops if e > w0 and s < w1]
+        in_step = _in_runs(inside, runs)
+        inside = [(max(s, w0), min(e, w1), name) for s, e, name in inside]
+        for (_, _, name), own, mine in zip(inside, _exclusive(inside), in_step):
             kinds[op_kind(name, roots)] += own / n
             by_op[_short(name)] += own / n
+            stage, direction = by_instr.get(opcode(name)[0], ("", "forward")) if mine \
+                else ("", "forward")
+            by_stage[stage][direction] += own / n
         merged = _union((s, e) for s, e, _ in inside)
         busy += sum(e - s for s, e in merged) / n
         if i == 0:
@@ -203,6 +322,7 @@ def reduce(path, roots: dict[str, str] | None = None, top: int = 10) -> dict | N
         "window_s": (w1 - w0) * 1e-9,
         "busy_s": busy * 1e-9,
         "kind_s": {k: v * 1e-9 for k, v in kinds.items()},
+        "stage_s": {k: {d: v * 1e-9 for d, v in dirs.items()} for k, dirs in by_stage.items()},
         "device_ops": [[name, ns * 1e-9] for name, ns in ops_top],
         "idle_gaps": named,
     }
