@@ -66,7 +66,8 @@ def readings(h, seed: int, trainer=None, which=None) -> dict:
         else:
             out[v] = h.reference(seed, ring, fault=v)
         # the reference put in the program's place
-        out[v].update(rows=out[v]["state"]["tables"], dense=out[v]["state"]["dense"])
+        out[v].update(rows=out[v]["state"]["tables"], dense=out[v]["state"]["dense"],
+                      buffers=out[v]["state"]["buffers"])
         secs[v] = time.perf_counter() - t
     nums = {k: h.numbers(r, ref) for k, r in out.items()}
     nums["raw"] = {k: {n: r[n] for n in ("losses", "grad_norms", "change_norms")}

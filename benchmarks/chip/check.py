@@ -41,6 +41,14 @@ table (``sparse/dim<d>``). The numbers compared:
   reference's nonzero first-moment elements after step 1 whose sign the
   program's differs from (the direction of the element's first Adam
   update); the largest.
+* ``buffer_gap``: where the reference moves state by a rule and not by a
+  gradient (its ``buffers``, such as a router's per-expert bias), per
+  buffer leaf the norm of the difference between the program's leaf at the
+  same path of its train state and the reference's after the last step,
+  over the reference's change of that leaf (a leaf left unchanged reads
+  1); the worst leaf. A buffer the program keeps among the towers'
+  parameters (``dense/...``) is compared here only, and left out of the
+  numbers above.
 
 A cell's limits file names the numbers it compares; the others are
 reported beside them with no limit.
@@ -52,7 +60,7 @@ import statistics
 import numpy as np
 
 NUMBERS = ("loss_gap", "grad_gap", "change_gap", "row_gap", "dense_gap", "moment_gap",
-           "sign_flip_share")
+           "sign_flip_share", "buffer_gap")
 TINY = 1e-3
 
 
@@ -246,11 +254,45 @@ def _worst(prog: dict, ref: dict, keep=None) -> tuple[float, str]:
     return worst, leaf
 
 
+def _buffer_gap(prog: dict, ref_state: dict) -> tuple[float, str]:
+    worst, leaf = 0.0, ""
+    for k, end in sorted(ref_state["buffers"].items()):
+        if k not in prog:
+            raise ValueError(f"the program's readings have no buffer {k!r}")
+        end = np.asarray(end, np.float64)
+        diff = np.sum((np.asarray(prog[k], np.float64) - end) ** 2)
+        gap = 0.0 if diff == 0 else _ratio(diff, np.sum((end - ref_state["buffers0"][k]) ** 2))
+        if gap >= worst:
+            worst, leaf = gap, k
+    return worst, leaf
+
+
+def _without_buffers(prog: dict, names) -> dict:
+    """The program's readings less the buffers it keeps among the towers'
+    parameters (``dense/<path>``)."""
+    dense = {n[len("dense/"):] for n in names if n.startswith("dense/")}
+    if not dense:
+        return prog
+    out = dict(prog)
+    for k in ("grad_norms", "change_norms"):
+        out[k] = {n: v for n, v in prog[k].items() if n not in names}
+    if "dense" in prog:
+        out["dense"] = {n: v for n, v in prog["dense"].items() if n not in dense}
+    if "m1" in prog:
+        out["m1"] = dict(prog["m1"], dense={n: v for n, v in prog["m1"]["dense"].items()
+                                            if n not in dense})
+    return out
+
+
 def numbers(prog: dict, ref: dict, init_rows=None) -> dict:
     """{number: (value, worst leaf or step)} of one run. ``row_gap`` and
     ``dense_gap`` need the program's ``rows`` and ``dense``, the reference's
     ``state`` and ``init_rows(keys, dim)`` on the host; ``moment_gap`` and
-    ``sign_flip_share`` need both sides' ``m1``."""
+    ``sign_flip_share`` need both sides' ``m1``; ``buffer_gap``, where the
+    reference's ``state`` has buffers, the program's ``buffers``."""
+    buffers = ref.get("state", {}).get("buffers")
+    if buffers:
+        prog = _without_buffers(prog, buffers)
     steps = [abs(a - b) for a, b in zip(prog["losses"], ref["losses"])]
     if len(prog["losses"]) != len(ref["losses"]):
         steps.append(float("inf"))
@@ -267,17 +309,22 @@ def numbers(prog: dict, ref: dict, init_rows=None) -> dict:
         out["dense_gap"] = (_dense_gap(prog["dense"], ref["state"]), "dense")
     if "m1" in prog and "m1" in ref:
         out["moment_gap"], out["sign_flip_share"] = _moment_gap(prog["m1"], ref["m1"])
+    if buffers:
+        out["buffer_gap"] = _buffer_gap(prog.get("buffers", {}), ref["state"])
     return out
 
 
 def judge(nums: dict, limits: dict | None) -> tuple[bool, dict]:
     """(correct, {number: {"value", "limit"}}): correct when every number
     the limits name is present and within its limit; no limits, not
-    correct. Numbers the limits do not name are reported with none."""
+    correct. Numbers the limits do not name are reported with none, where
+    the run reads them."""
     limits = {k: v for k, v in (limits or {}).items() if k in NUMBERS}
     ok = bool(limits)
     out = {}
     for name in NUMBERS:
+        if name not in nums and name not in limits:
+            continue
         value = nums[name][0] if name in nums else None
         if name in limits:
             ok = ok and value is not None and value <= limits[name]

@@ -53,3 +53,37 @@ def make_root(tmp: pathlib.Path) -> pathlib.Path:
 @pytest.fixture
 def smoke_root(tmp_path):
     return make_root(tmp_path)
+
+
+LM_CELL = "qwen2.5-smoke.train.tokens"
+
+
+def add_lm_cell(root: pathlib.Path) -> str:
+    """Add to a checkout ``qwen2.5-smoke.train.tokens``: the program's
+    smoke-size qwen2.5-3b (2 layers, 64 wide, GQA 4 over 2 heads, qkv bias,
+    a 512-token vocabulary) at 4 sequences of 64 tokens, dlrm-mlperf's
+    optimizers, its plain reference (``testdata/qwen2.5-3b.py``) and a token
+    mix of Zipf(1.0) ids over the vocabulary, 2 checked steps."""
+    import dataclasses
+
+    from repro.configs import get_config
+
+    bench = root / "benchmarks" / "chip"
+    arch = get_config("qwen2.5-3b", smoke=True)
+    base = json.loads((bench / "configs" / "dlrm-mlperf.json").read_text())
+    cfg = {"arch": arch.arch_id, "arch_smoke": True,
+           "model": json.loads(json.dumps(dataclasses.asdict(arch.model))),
+           "global_batch": 4, "seq_len": 64, "precision": base["precision"],
+           "optimizer": base["optimizer"]}
+    (bench / "configs" / "qwen2.5-smoke.json").write_text(json.dumps(cfg))
+    shutil.copy(BENCH / "testdata" / "qwen2.5-3b.py", bench / "reference" / "qwen2.5-smoke.py")
+    mix = {"kind": "train", "ids": "zipf", "zipf_exponent": 1.0, "vocab": "model.vocab_size",
+           "id_space": "vocab", "ring_batches": 4, "check_steps": 2}
+    (bench / "traffic" / "train.tokens.json").write_text(json.dumps(mix))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "qwen2.5-smoke", "source": "smoke", "reduced": [],
+                            "why": "smoke", "file": "benchmarks/chip/configs/qwen2.5-smoke.json"})
+    spec["workloads"].append({"name": LM_CELL, "config": "qwen2.5-smoke",
+                              "traffic": "train.tokens", "chips": 1, "why": "smoke"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
+    return LM_CELL

@@ -8,11 +8,14 @@ can have; ``none`` plants nothing):
 
 * ``state_unchanged``: the train step returns the state it was given;
 * ``half_batch``: the loss is the mean over the first half of the batch,
-  the rest left out;
+  the rest left out; a language model's loss runs on the first half of each
+  sequence (attention is causal, so that is exactly those tokens' loss);
 * ``no_exchange``: the all-to-all between chips is left out (each chip
   looks its own ids up in its own shard);
 * ``altered_id``: the feature hash flips the low bit of the first hashed
-  column's ids where it produces them.
+  column's ids where it produces them; a language model's train step flips
+  the low bit of every token id where it looks its rows up (its labels stay
+  the tokens it was given).
 
 ``--no-chip`` skips the harness's look for a chip (the CPU tests).
 """
@@ -54,6 +57,16 @@ def plant(fault: str, src: pathlib.Path) -> None:
 
         for mod in (dlrm, wide_deep):
             mod.bce_with_logits = half
+
+        from repro.models import transformer
+
+        lm_loss = transformer.lm_loss
+
+        def half_lm(params, cfg, x_emb, labels, *a, **k):
+            t = x_emb.shape[1] // 2
+            return lm_loss(params, cfg, x_emb[:, :t], labels[:, :t], *a, **k)
+
+        transformer.lm_loss = half_lm
     elif fault == "no_exchange":
         jax.lax.all_to_all = lambda x, *a, **k: x
     elif fault == "altered_id":
@@ -67,6 +80,16 @@ def plant(fault: str, src: pathlib.Path) -> None:
             return hashed(values, column_ids, salts) ^ (column_ids == 0).astype(jnp.int64)
 
         feature_engine.fused_hash = fused_hash
+
+        from repro.launch import lm_cell
+
+        fetch_sm = lm_cell._fetch_sm
+
+        def _fetch_sm(*a, **k):
+            fetch, espec = fetch_sm(*a, **k)
+            return (lambda state, ids, step: fetch(state, ids ^ 1, step)), espec
+
+        lm_cell._fetch_sm = _fetch_sm
     else:
         raise ValueError(f"unknown fault {fault!r}; known: {FAULTS}")
 

@@ -4,6 +4,9 @@
 (``repro.launch.cells.build_cell`` with ``CellOptions(chip_table_rows=...)``),
 the traffic ring of a seed, fresh state with the towers drawn from the seed,
 the Trainer, the checked first steps, and the reference's replay of them.
+Every cell takes the program's own default for recomputing activations (a
+language model's step recomputes its layers, as a training job chooses to
+fit; a recommendation cell has none to choose).
 
 It names no family of the program. What differs between families (the
 train shape's parameters, the towers' initialiser, the engine whose tables
@@ -20,6 +23,7 @@ the initialiser's towers have the shapes of the cell's state.
 from __future__ import annotations
 
 import dataclasses
+import json
 import os
 import pathlib
 import sys
@@ -44,10 +48,24 @@ def use_cache() -> None:
 
 
 def _check_model(arch, cfg: dict) -> None:
-    have = {k: list(v) if isinstance(v, tuple) else v
-            for k, v in dataclasses.asdict(arch.model).items() if k != "vocab_per_feature"}
+    """The configuration's ``model`` is the program's, compared as JSON
+    (a tuple at any depth reads as a list)."""
+    have = json.loads(json.dumps({k: v for k, v in dataclasses.asdict(arch.model).items()
+                                  if k != "vocab_per_feature"}))
     if have != cfg["model"]:
         raise ValueError(f"config {cfg['model']} is not the program's {arch.arch_id}: {have}")
+
+
+def _leaf(state, name: str):
+    """The leaf of the program's train state at ``name``, a path of dict
+    keys joined by ``/``."""
+    node = state
+    for part in name.split("/"):
+        if not isinstance(node, dict) or part not in node:
+            raise ValueError(f"the program's train state has no {name!r}: the reference's "
+                             f"buffers are named by their paths in it")
+        node = node[part]
+    return node
 
 
 def _check_optimizer(cfg: dict) -> None:
@@ -138,7 +156,7 @@ class Harness:
         self.mesh = make_mesh(devices=self.devices)
         self.batch = int(cfg["global_batch"])
         shape = ShapeCell("train_batch", "train", family.shape(cfg))
-        opts = CellOptions(remat=False, zero1=False,
+        opts = CellOptions(zero1=False,
                            chip_table_rows=cfg.get("rows_per_table_per_chip"),
                            sparse_opt_lr=cfg["optimizer"]["sparse"]["lr"],
                            dense_opt_lr=cfg["optimizer"]["dense"]["lr"])
@@ -161,6 +179,11 @@ class Harness:
                       jax.eval_shape(init_dense, jax.random.PRNGKey(0)),
                       self.cell.abstract_state["dense"])
         self._init_dense = jax.jit(init_dense, out_shardings=self.state_sh["dense"])
+        # the leaves that the reference moves by a rule, by their paths in
+        # the program's train state; and its programs, made on first use
+        self.buffers = [] if self.ref is None or not hasattr(self.ref, "init_buffers") else \
+            list(common.flat(jax.eval_shape(lambda: self.ref.init_buffers(cfg))))
+        self._ref_steps, self._ref_loss_grad = None, {}
 
     # ------------------------------------------------------------- inputs
     def ring(self, seed: int) -> list[dict]:
@@ -231,6 +254,9 @@ class Harness:
         prog["rows"] = check.live_rows(res.state, self.mesh)
         prog["dense"] = {k: np.asarray(v) for k, v in
                          check.flat(jax.device_get(res.state["dense"])).items()}
+        if self.buffers:
+            prog["buffers"] = {name: np.asarray(jax.device_get(_leaf(res.state, name)))
+                               for name in self.buffers}
         return res.state, prog
 
     def numbers(self, prog: dict, ref: dict) -> dict:
@@ -243,8 +269,13 @@ class Harness:
             raise ValueError(f"{self.workload}: its configuration has no reference")
         host = [{k: v for k, v in b.items() if not k.startswith("_")}
                 for b in ring[:self.n_check]]
-        return self.common.run(self.ref, self.cfg, seed, host, self.chips,
-                               quantize=quantize or self.common.identity, fault=fault)
+        key = (quantize or self.common.identity, fault == "half_batch")
+        if self._ref_steps is None:
+            self._ref_steps = self.common.make_steps(self.ref, self.cfg)
+        if key not in self._ref_loss_grad:
+            self._ref_loss_grad[key] = self.common.make_loss_grad(self.ref, self.cfg, *key)
+        return self.common.run(self.ref, self.cfg, seed, host, self.chips, fault=fault,
+                               steps=self._ref_steps, loss_grad=self._ref_loss_grad[key])
 
     @staticmethod
     def free(state) -> None:

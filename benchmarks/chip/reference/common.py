@@ -12,12 +12,28 @@ loss, and the two optimizers. Each is written out here from that contract:
   FNV-1a;
 * a new row of width ``d`` is ``(2 u - 1) / sqrt(d)``, ``u`` the top 24 bits
   of ``splitmix64(key * C1 + column)``; its Adam moments start at 0;
-* the loss is the mean over the batch of each example's loss, which the
-  configuration's module gives (``example_losses``: a recommendation
-  model's sigmoid cross-entropy, ``sigmoid_ce``);
+* the loss is the mean of the losses that the configuration's module gives
+  (``example_losses``), one to an example. An example is whatever
+  ``example_losses`` returns one loss for: a recommendation model's row,
+  with its sigmoid cross-entropy (``sigmoid_ce``); a language model's token,
+  so that it returns (B·T,) losses. ``example_losses`` sees every column of
+  the batch as its raw values, the id columns too: a language model reshapes
+  ``batch["tokens"]`` to (B, T) for its next-token labels. The
+  ``half_batch`` fault leaves out the second half of those losses, so for a
+  language model half the tokens, even at a global batch of 1;
 * the towers' parameters take AdamW with the gradient clipped to a global
   norm (decoupled weight decay), the rows take lazy row-wise Adam: only rows
-  of the batch move, with bias correction by the global step.
+  of the batch move, with bias correction by the global step;
+* state that moves by a rule and not by a gradient (a router's per-expert
+  bias, updated from the load after each step) is the module's: where it
+  defines ``init_buffers(cfg)``, ``example_losses`` takes the buffers as
+  its last argument and returns ``(losses, new_buffers)``, and ``run``
+  threads them from step to step and returns them at the start and the
+  end. A buffer's path in ``init_buffers``'s dicts is the path of the
+  same leaf in the program's train state (``dense/...`` where the program
+  keeps it among the towers' parameters), where ``check`` reads the
+  program's to compare (``buffer_gap``). A module without it is called
+  with the five arguments alone.
 
 The reference keeps one row per distinct key of the steps it runs (a plain
 lookup table), and computes the towers in float32, every matrix product at
@@ -26,6 +42,15 @@ the ``highest`` precision. ``quantize`` rounds every value of the towers
 as the program rounds them to bfloat16; ``fp8`` is how the control steps
 one precision below that, and ``bf16`` rounds them as the program does (a
 witness of what bfloat16 alone moves, not a control).
+
+It is built to fit one chip beside nothing else: the towers' starting
+parameters and each table's initial rows are held on the host, the AdamW
+step takes the parameters, their moments and the gradient in place
+(donated), and the clipped gradient is kept only at step 1, where its norms
+are read. The device holds four copies of the towers at once (the
+parameters, their two moments and one gradient), and five at most, inside
+the AdamW step, where the compiler does not write the clipped gradient
+over the raw one.
 """
 from __future__ import annotations
 
@@ -94,18 +119,23 @@ def init_rows(keys: jax.Array, dim: int) -> jax.Array:
 
 
 _init_rows = jax.jit(init_rows, static_argnums=1)
-BUCKET = 1 << 16
+BUCKET_ROWS = 1 << 16
+BUCKET_VALUES = 1 << 23
 
 
-def bucket(n: int) -> int:
-    """``n`` rounded up to a multiple of 65,536: arrays of that many rows
-    have the same shape from seed to seed, so their programs compile once."""
-    return max(BUCKET, -(-n // BUCKET) * BUCKET)
+def bucket(n: int, dim: int) -> int:
+    """``n`` rows of width ``dim`` rounded up to a multiple of 2**23 values
+    (65,536 rows at 128 wide, 4,096 at 2,048), and of at most 65,536 rows
+    (narrower tables pad as they always did): arrays of that many rows have
+    the same shape from seed to seed, so their programs compile once, and a
+    wide table pads by at most 32 MiB."""
+    step = max(1, min(BUCKET_ROWS, BUCKET_VALUES // dim))
+    return max(step, -(-n // step) * step)
 
 
 def init_rows_host(keys: np.ndarray, dim: int) -> np.ndarray:
     """``init_rows`` of host keys, on the host."""
-    padded = np.zeros(bucket(len(keys)), np.int64)
+    padded = np.zeros(bucket(len(keys), dim), np.int64)
     padded[:len(keys)] = keys
     return np.asarray(_init_rows(jnp.asarray(padded), dim))[:len(keys)]
 
@@ -234,8 +264,69 @@ def _column_keys(model, cfg, batch: dict, chips: int, fault: str | None):
     return out
 
 
+def _host(x) -> np.ndarray:
+    """A copy of ``x`` on the host (not a view of a buffer that a later
+    step may take in place)."""
+    return np.array(x, copy=True)
+
+
+def _towers(init_dense, seed: int):
+    """The towers' parameters drawn from the seed, on the device; a copy of
+    them on the host (the start that each leaf's change is taken from);
+    their zero Adam moments, on the device."""
+    params = init_dense(jax.random.PRNGKey(seed))
+    return (params, jax.tree.map(_host, params), jax.tree.map(jnp.zeros_like, params),
+            jax.tree.map(jnp.zeros_like, params))
+
+
+def _tables(per_step: list) -> dict:
+    """Per group: the rows of every distinct key of the steps (``emb``, on
+    the device), their initial values (``init``, on the host), zero Adam
+    moments, the keys, and each step's index of its rows into the table."""
+    tables = {}
+    for group in per_step[0]:
+        dim = per_step[0][group][0]
+        idents = np.concatenate([s[group][2].reshape(-1, *s[group][2].shape[2:])
+                                 for s in per_step])
+        keys = np.concatenate([s[group][1].reshape(-1) for s in per_step])
+        _, first, inv = np.unique(idents, axis=0 if idents.ndim > 1 else None,
+                                     return_index=True, return_inverse=True)
+        inv = inv.reshape(-1)
+        # the table holds bucket(n, dim) rows; the rows past the n keys are
+        # never looked up, and are left out of what is returned
+        padded = np.zeros(bucket(len(first), dim), np.int64)
+        padded[:len(first)] = keys[first]
+        rows0 = _init_rows(jnp.asarray(padded), dim)
+        tables[group] = {"emb": rows0, "init": _host(rows0), "keys": keys[first],
+                         "n": len(first), "m": jnp.zeros_like(rows0),
+                         "v": jnp.zeros_like(rows0), "inv": np.split(inv, len(per_step))}
+    return tables
+
+
+def make_steps(model, cfg: dict):
+    """The reference's jitted programs that no compute precision or fault
+    changes: the towers drawn from a key; AdamW, which takes the
+    parameters, the gradient and the moments in place; and row-wise Adam,
+    which takes the table in place."""
+    dhp, shp = cfg["optimizer"]["dense"], cfg["optimizer"]["sparse"]
+    init_dense = jax.jit(lambda key: model.init_dense(key, cfg))
+    dense_step = jax.jit(lambda p, g, m, v, t: _adamw(dhp, p, g, m, v, t),
+                         donate_argnums=(0, 1, 2, 3))
+    row_step = jax.jit(lambda t_, g, inv, t: _sparse_adam(shp, t_, g, inv, t),
+                       donate_argnums=0)
+    return init_dense, dense_step, row_step
+
+
+def make_loss_grad(model, cfg: dict, quantize=identity, half: bool = False):
+    """The jitted loss, the buffers after the step and the gradients, in a
+    compute precision and under the ``half_batch`` fault (``half``)."""
+    return jax.jit(jax.value_and_grad(
+        lambda prm, rows, b, buf: _loss(model, cfg, prm, rows, b, buf, quantize, half),
+        argnums=(0, 1), has_aux=True))
+
+
 def run(model, cfg: dict, seed: int, batches: list, chips: int,
-        quantize=identity, fault: str | None = None) -> dict:
+        quantize=identity, fault: str | None = None, steps=None, loss_grad=None) -> dict:
     """Train ``len(batches)`` steps from the seed; return the readings.
 
     ``batches`` are host batches ({column: (values, row_splits)}), as many
@@ -245,41 +336,22 @@ def run(model, cfg: dict, seed: int, batches: list, chips: int,
     ``"sparse/<group>"``; the first moments after step 1 (``m1``: the
     towers' per leaf, and per group the rows that step touched, with their
     keys); and the ``state``: the towers' parameters at the start and the
-    end, and each group's rows with their keys and moments.
+    end, each group's rows with their keys and moments, and the buffers at
+    the start and the end (``buffers0``, ``buffers``: {path: value}, empty
+    without ``init_buffers``). ``steps`` and ``loss_grad`` are
+    ``make_steps(model, cfg)`` and ``make_loss_grad(model, cfg, quantize,
+    fault == "half_batch")``, made here where not given: a caller that
+    replays many seeds makes them once.
     """
-    hp = cfg["optimizer"]
-    dhp, shp = hp["dense"], hp["sparse"]
     with jax.default_matmul_precision("highest"):
-        params = model.init_dense(jax.random.PRNGKey(seed), cfg)
-        p0 = jax.tree.map(jnp.array, params)
-        m = jax.tree.map(jnp.zeros_like, params)
-        v = jax.tree.map(jnp.zeros_like, params)
+        init_dense, dense_step, row_step = steps or make_steps(model, cfg)
+        loss_grad = loss_grad or make_loss_grad(model, cfg, quantize, fault == "half_batch")
+        params, p0, m, v = _towers(init_dense, seed)
+        buffers = model.init_buffers(cfg) if hasattr(model, "init_buffers") else None
+        buffers0 = {} if buffers is None else {k: _host(x) for k, x in flat(buffers).items()}
 
         per_step = [_column_keys(model, cfg, b, chips, fault) for b in batches]
-        tables = {}
-        for group in per_step[0]:
-            dim = per_step[0][group][0]
-            idents = np.concatenate([s[group][2].reshape(-1, *s[group][2].shape[2:])
-                                     for s in per_step])
-            keys = np.concatenate([s[group][1].reshape(-1) for s in per_step])
-            uniq, first, inv = np.unique(idents, axis=0 if idents.ndim > 1 else None,
-                                         return_index=True, return_inverse=True)
-            inv = inv.reshape(-1)
-            # the table holds bucket(n) rows; the rows past the n keys are
-            # never looked up, and are left out of what is returned
-            padded = np.zeros(bucket(len(first)), np.int64)
-            padded[:len(first)] = keys[first]
-            rows0 = _init_rows(jnp.asarray(padded), dim)
-            tables[group] = {"emb": rows0, "init": rows0, "keys": keys[first], "n": len(first),
-                             "m": jnp.zeros_like(rows0), "v": jnp.zeros_like(rows0),
-                             "inv": np.split(inv, len(batches))}
-
-        loss_grad = jax.jit(jax.value_and_grad(
-            lambda prm, rows, b: _loss(model, cfg, prm, rows, b, quantize, fault),
-            argnums=(0, 1)))
-        dense_step = jax.jit(lambda p, g, m, v, t: _adamw(dhp, p, g, m, v, t))
-        row_step = jax.jit(lambda t_, g, inv, t: _sparse_adam(shp, t_, g, inv, t))
-
+        tables = _tables(per_step)
         losses, grad_norms, m1 = [], {}, {"dense": {}, "rows": {}}
         for k, b in enumerate(batches):
             t = jnp.float32(k + 1)
@@ -287,10 +359,11 @@ def run(model, cfg: dict, seed: int, batches: list, chips: int,
             for group, tab in tables.items():
                 shape = per_step[k][group][1].shape
                 rows[group] = tab["emb"][jnp.asarray(tab["inv"][k])].reshape(*shape, -1)
-            host = {name: jnp.asarray(vals) for name, (vals, _) in b.items()
-                    if vals.dtype != np.int64}
-            loss, (g_dense, g_rows) = loss_grad(params, rows, host)
+            host = {name: jnp.asarray(vals) for name, (vals, _) in b.items()}
+            (loss, buffers), (g_dense, g_rows) = loss_grad(params, rows, host, buffers)
             losses.append(float(loss))
+            # the gradient goes into the step in place; the clipped one that
+            # comes back is read at step 1 and dropped
             params, m, v, g_used = dense_step(params, g_dense, m, v, t)
             for group, tab in tables.items():
                 g = g_rows[group].reshape(-1, g_rows[group].shape[-1])
@@ -301,32 +374,42 @@ def run(model, cfg: dict, seed: int, batches: list, chips: int,
                     hit = np.unique(tab["inv"][0])
                     m1["rows"][group] = {"keys": tab["keys"][hit],
                                          "m": np.asarray(tab["m"])[hit]}
+                del g_tab
             if k == 0:
                 for name, g in flat(g_used).items():
                     grad_norms[f"dense/{name}"] = float(jnp.linalg.norm(g))
-                m1["dense"] = {name: np.asarray(x) for name, x in flat(m).items()}
+                m1["dense"] = {name: _host(x) for name, x in flat(m).items()}
+            del g_used
 
-        change = {f"dense/{name}": float(jnp.linalg.norm(a - b))
+        # each leaf's change, one leaf's start on the device at a time
+        change = {f"dense/{name}": float(jnp.linalg.norm(a - jnp.asarray(b)))
                   for (name, a), b in zip(flat(params).items(), flat(p0).values())}
         for group, tab in tables.items():
-            change[f"sparse/{group}"] = float(jnp.linalg.norm(tab["emb"] - tab["init"]))
+            change[f"sparse/{group}"] = float(jnp.linalg.norm(
+                tab["emb"] - jnp.asarray(tab["init"])))
     return {
         "losses": losses, "grad_norms": grad_norms, "change_norms": change, "m1": m1,
         "state": {
             "dense": {k: np.asarray(v) for k, v in flat(params).items()},
-            "dense0": {k: np.asarray(v) for k, v in flat(p0).items()},
+            "dense0": flat(p0),
             "tables": {g: {"keys": t["keys"],
                            **{k: np.asarray(t[k])[:t["n"]] for k in ("emb", "m", "v")}}
-                       for g, t in tables.items()}}}
+                       for g, t in tables.items()},
+            "buffers0": buffers0,
+            "buffers": {} if buffers is None else {k: _host(x) for k, x in flat(buffers).items()}}}
 
 
-def _loss(model, cfg, params, rows, batch, quantize, fault):
-    """The mean of the model's per-example losses; under the ``half_batch``
-    fault, of the first half of the batch."""
-    losses = model.example_losses(params, cfg, rows, batch, quantize)
-    if fault == "half_batch":
+def _loss(model, cfg, params, rows, batch, buffers, quantize, half: bool):
+    """The mean of the model's per-example losses, and the buffers after
+    the step (None without them); with ``half`` (the ``half_batch`` fault),
+    the mean of the first half of the losses."""
+    if buffers is None:
+        losses = model.example_losses(params, cfg, rows, batch, quantize)
+    else:
+        losses, buffers = model.example_losses(params, cfg, rows, batch, quantize, buffers)
+    if half:
         losses = losses[:losses.shape[0] // 2]
-    return jnp.mean(losses)
+    return jnp.mean(losses), buffers
 
 
 def _adamw(hp, params, grads, m, v, t):

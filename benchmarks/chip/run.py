@@ -18,7 +18,8 @@ window's step counters and the engine's exchange budgets: ``context`` lists
 what a metric's ``read(ctx)`` sees.
 
 After the window the program's state is freed and the plain reference
-replays the first steps; ``check.py`` compares the two. The last lines of
+replays the first steps (its wall time is ``window.reference_s``: every
+run pays it, outside ``setup_s``); ``check.py`` compares the two. The last lines of
 standard error give each compared number beside its limit, and the last
 line of standard output is the result as one JSON object.
 
@@ -157,7 +158,9 @@ def run(argv=None, root: pathlib.Path = ROOT, require_tpu: bool = True) -> dict 
     # --------------------------------------- free the program, then check
     h.free(w.res.state)
     del state, trainer
+    t_ref = time.perf_counter()
     ref = h.reference(args.seed, ring)
+    reference_s = time.perf_counter() - t_ref
     nums = h.numbers(prog, ref)
     correct, compared = check.judge(nums, h.bench.limits(args.workload))
 
@@ -177,6 +180,7 @@ def run(argv=None, root: pathlib.Path = ROOT, require_tpu: bool = True) -> dict 
     out["window"] = {
         "compiles": sum(x.get("compiles", 0) for x in hist),
         "peak_bytes_step1": prog["peak_bytes_step1"],
+        "reference_s": reference_s,
         "new_id_share": inserted / max(1, sum(sum(u.values()) for u in ctx.unique_per_step)),
         "op_kind_s": summary and summary["kind_s"],
         "stage_s": summary and summary["stage_s"],

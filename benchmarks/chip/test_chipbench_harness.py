@@ -169,6 +169,61 @@ def test_language_model_added_as_new_files_runs_through_the_harness(smoke_root):
     assert (_tree_digest(BENCH), (ROOT / "BENCHMARK.json").read_bytes()) == before
 
 
+def test_a_language_model_cell_recomputes(smoke_root):
+    """A language model's cell takes the program's default and recomputes
+    its layers' activations: its step holds a ``checkpoint`` (which the
+    gradient's jaxpr prints as ``remat``)."""
+    import jax
+
+    import conftest
+    import harness
+
+    cell = conftest.add_lm_cell(smoke_root)
+    h = harness.Harness(smoke_root, cell, jax.devices())
+    jaxpr = str(jax.make_jaxpr(h.cell.step_fn)(h.cell.abstract_state, h.cell.batch_specs))
+    assert any(w in jaxpr for w in ("checkpoint", "remat"))
+
+
+def test_model_check_compares_nested_groups_as_json():
+    """A configuration's ``model`` is compared with the program's after a
+    JSON round trip, so a tuple in a nested group reads as the list that
+    the configuration file holds; a number that differs there is refused."""
+    import dataclasses
+    import types
+
+    import harness
+
+    @dataclasses.dataclass(frozen=True)
+    class Experts:
+        n: int = 64
+        widths: tuple = (1408, 2816)
+
+    @dataclasses.dataclass(frozen=True)
+    class Model:
+        d_model: int = 2048
+        moe: Experts = Experts()
+        dims: tuple = (128, 64)
+
+    arch = types.SimpleNamespace(arch_id="toy", model=Model())
+    stated = {"d_model": 2048, "moe": {"n": 64, "widths": [1408, 2816]}, "dims": [128, 64]}
+    harness._check_model(arch, {"model": stated})
+    stated["moe"]["widths"] = [1408, 1408]
+    with pytest.raises(ValueError, match="is not the program's toy"):
+        harness._check_model(arch, {"model": stated})
+
+
+def test_buffers_are_read_at_their_paths_in_the_train_state():
+    """The program's leaf of a reference's buffer is read at the buffer's
+    path of dict keys in the train state; a path the state lacks is
+    refused by name."""
+    import harness
+
+    state = {"dense": {"layers": {"router_bias": 1.5}}, "sparse": {}}
+    assert harness._leaf(state, "dense/layers/router_bias") == 1.5
+    with pytest.raises(ValueError, match="no 'dense/router_bias'"):
+        harness._leaf(state, "dense/router_bias")
+
+
 def test_a_configuration_without_its_familys_numbers_is_refused(smoke_root):
     """A recommendation configuration that leaves out its rows per table
     per chip is refused before a cell is built."""

@@ -1,9 +1,12 @@
 """CPU tests of the plain references against the program at smoke size:
 the same keys (of hashed columns and of token columns with many ids to a
-row), the same initial rows, and, over the checked steps, the same losses,
-rows with their Adam moments, and tower parameters to within what the
-program's bfloat16 tower products allow; and the references' own readings
-at one seed, which are recorded to the bit."""
+row), the same initial rows and towers, and, over the checked steps, the
+same losses, rows with their Adam moments, and tower parameters to within
+what the program's bfloat16 tower products allow; the references' own
+readings at one seed, which are recorded to the bit; and how the reference
+runs: what it keeps on the host, what its AdamW step takes in place, and
+the state it threads from step to step by a rule."""
+import dataclasses
 import json
 import pathlib
 import sys
@@ -179,3 +182,129 @@ def test_checked_steps_match_the_reference(smoke_root, workload):
         diff += np.sum(d.astype(np.float64) ** 2)
         moved += np.sum((v - dense0[k]) ** 2)
     assert np.sqrt(diff) <= TOL * np.sqrt(moved)
+
+
+def test_language_model_reference_draws_the_programs_towers():
+    """``testdata/qwen2.5-3b.py`` draws the towers key for key as the
+    program's ``transformer.init`` does, leaf by leaf and to the bit (each
+    in one jitted call, as the harness and the reference draw them)."""
+    from repro.configs import get_config
+    from repro.models import transformer as tfm
+
+    arch = get_config("qwen2.5-3b", smoke=True)
+    cfg = {"model": json.loads(json.dumps(dataclasses.asdict(arch.model)))}
+    ref = registry.load(BENCH / "testdata", "qwen2.5-3b")
+    key = jax.random.PRNGKey(7)
+    prog = check.flat(jax.device_get(jax.jit(lambda k: tfm.init(k, arch.model))(key)))
+    mine = check.flat(jax.device_get(jax.jit(lambda k: ref.init_dense(k, cfg))(key)))
+    assert prog.keys() == mine.keys()
+    for k in prog:
+        np.testing.assert_array_equal(mine[k], prog[k], err_msg=k)
+
+
+def test_reference_keeps_the_starts_on_the_host_and_steps_in_place():
+    """At smoke size: the towers' start and each table's initial rows come
+    back as host arrays beside the device's parameters and rows, and the
+    AdamW step takes the parameters, the gradient and the moments in place
+    (the arrays given to it are gone after it)."""
+    bench = registry.Bench(ROOT)
+    cfg = dict(bench.config("dlrm-mlperf"), model=conftest.SMOKE_MODELS["dlrm-mlperf"],
+               rows_per_table_per_chip=512, global_batch=256)
+    model = registry.load(BENCH / "reference", "dlrm-mlperf")
+    init_dense, dense_step, _ = common.make_steps(model, cfg)
+    params, p0, m, v = common._towers(init_dense, 2**31 + 7)
+    flat_p, flat_p0 = check.flat(params), check.flat(p0)
+    assert flat_p.keys() == flat_p0.keys()
+    for k, x in flat_p0.items():
+        assert isinstance(x, np.ndarray) and isinstance(flat_p[k], jax.Array)
+        np.testing.assert_array_equal(x, np.asarray(flat_p[k]))
+
+    mix = dict(bench.traffic("train.zipf"), ring_batches=1)
+    (batch,) = traffic.make_ring(mix, cfg, 1, _smoke_columns(model, cfg, 1), 2**31 + 7)
+    (tab,) = common._tables([common._column_keys(model, cfg, batch, 1, None)]).values()
+    assert isinstance(tab["init"], np.ndarray) and isinstance(tab["emb"], jax.Array)
+    assert tab["init"].shape == tab["emb"].shape == (common.bucket(tab["n"], 16), 16)
+    np.testing.assert_array_equal(tab["init"][:tab["n"]],
+                                  common.init_rows_host(tab["keys"], 16))
+
+    grads = jax.tree.map(jnp.ones_like, params)
+    given = jax.tree.leaves((params, grads, m, v))
+    dense_step(params, grads, m, v, jnp.float32(1))
+    assert all(x.is_deleted() for x in given)
+
+
+def test_table_padding_is_a_multiple_of_2_to_the_23_values():
+    """65,536 rows at 128 wide (as before) and at any narrower width, 4,096
+    rows at 2,048 wide; never fewer than one multiple."""
+    assert common.bucket(1, 128) == common.bucket(65_536, 128) == 65_536
+    assert common.bucket(65_537, 128) == 131_072
+    assert common.bucket(1, 16) == common.bucket(65_536, 8) == 65_536
+    assert common.bucket(20_480, 2048) == 20_480
+    assert common.bucket(20_481, 2048) == 24_576
+    assert common.bucket(1, 2048) == 4_096
+
+
+class _Counted:
+    """A toy configuration: one scalar tower ``w`` and no table; each
+    example's loss is (1 + s) w, where ``s`` is a buffer that counts the
+    steps run, moved by a rule and not by the gradient, which the program
+    would keep among its towers' parameters (``dense/s``). Its examples are
+    the raw ids of an int64 column."""
+
+    @staticmethod
+    def groups(cfg):
+        return []
+
+    @staticmethod
+    def init_dense(key, cfg):
+        return {"w": jnp.float32(2.0)}
+
+    @staticmethod
+    def init_buffers(cfg):
+        return {"dense": {"s": jnp.float32(0.0)}}
+
+    @staticmethod
+    def example_losses(params, cfg, rows, batch, quantize, buffers):
+        ids = batch["ids"]
+        assert ids.dtype == jnp.int64
+        s = buffers["dense"]["s"]
+        losses = (1 + s) * params["w"] * jnp.ones(ids.shape, jnp.float32)
+        return losses, {"dense": {"s": s + 1}}
+
+
+def test_buffers_are_threaded_from_step_to_step():
+    """``init_buffers`` gives the state that ``example_losses`` takes and
+    returns; ``run`` hands each step's buffers to the next. Step k's loss is
+    k w_{k-1}, and since every gradient (k) clips to 1, AdamW moves ``w`` by
+    lr (1 + wd w) a step (to within its eps). The buffers come back at the
+    start and the end, and ``buffer_gap`` compares the program's leaf at
+    their path with the reference's: a program that moves it as the
+    reference does reads 0, one that leaves it unchanged reads 1 and not
+    ``correct``; the program's own readings of that leaf, kept among its
+    towers' parameters, are left out of the other numbers."""
+    cfg = {"optimizer": registry.Bench(ROOT).config("dlrm-mlperf")["optimizer"]}
+    batch = {"ids": (np.arange(8, dtype=np.int64) * 2**40, np.arange(9, dtype=np.int32))}
+    r = common.run(_Counted, cfg, 0, [batch] * 3, 1)
+    hp = cfg["optimizer"]["dense"]
+    w, want = 2.0, []
+    for k in range(1, 4):
+        want.append(k * w)
+        w -= hp["lr"] * (1 + hp["weight_decay"] * w)
+    np.testing.assert_allclose(r["losses"], want, rtol=1e-6)
+    assert r["grad_norms"] == {"dense/w": 1.0}
+    np.testing.assert_allclose(r["state"]["dense"]["w"], w, rtol=1e-6)
+    assert (r["state"]["buffers0"], r["state"]["buffers"]) == ({"dense/s": 0.0}, {"dense/s": 3.0})
+
+    limits = {"loss_gap": 1e-6, "change_gap": 1e-6, "buffer_gap": 0.1}
+    for kept, gap in ((3.0, 0.0), (0.0, 1.0)):
+        # the reference in the program's place, reading the buffer as a
+        # program that keeps it among its towers' parameters would
+        prog = dict(r, rows={}, dense={**r["state"]["dense"], "s": np.float32(kept)},
+                    buffers={"dense/s": np.float32(kept)},
+                    grad_norms={**r["grad_norms"], "dense/s": 0.0},
+                    change_norms={**r["change_norms"], "dense/s": kept},
+                    m1={**r["m1"], "dense": {**r["m1"]["dense"], "s": np.float32(0)}})
+        nums = check.numbers(prog, r, common.init_rows_host)
+        assert nums["buffer_gap"] == (gap, "dense/s")
+        assert nums["loss_gap"][0] == nums["change_gap"][0] == nums["dense_gap"][0] == 0
+        assert check.judge(nums, limits)[0] is (gap == 0)
